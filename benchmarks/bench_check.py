@@ -9,10 +9,11 @@ the serve lowering donates its batch buffer, no hot path hides a host
 callback or an f64.  Nothing executes — the whole table traces in
 seconds, so regressions surface before any benchmark runs.
 
-Runs ``python -m repro.check --gate`` in a subprocess: the distributed
-contracts want 8 forced host devices, which must be set before jax
-import — the driver process has long since imported jax (same pattern as
-bench_dist_goss).  Standalone: ``python -m benchmarks.bench_check --gate``.
+Runs ``python -m repro.check --gate`` in a CPU subprocess
+(``JAX_PLATFORMS=cpu``): the distributed contracts want 8 forced host
+devices, which must be set before jax import — the driver process has long
+since imported jax (same pattern as bench_dist_goss).  Standalone:
+``python -m benchmarks.bench_check --gate``.
 """
 from __future__ import annotations
 
@@ -27,6 +28,9 @@ def gate() -> int:
     env["PYTHONPATH"] = src + (os.pathsep + env["PYTHONPATH"]
                                if env.get("PYTHONPATH") else "")
     env.pop("XLA_FLAGS", None)          # let __main__ force 8 devices
+    # the contracts trace on forced host devices; on a machine with a chip
+    # the child must never compete with a parent that already holds it
+    env["JAX_PLATFORMS"] = "cpu"
     r = subprocess.run([sys.executable, "-m", "repro.check", "--gate"],
                        env=env, text=True, capture_output=True, timeout=900)
     sys.stdout.write(r.stdout)

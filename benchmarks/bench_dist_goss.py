@@ -25,10 +25,11 @@ are additionally split over the data shards (reported as
 ``collective_bytes_per_shard``).  Both numbers are deterministic functions
 of the built trees, not wall-clocks.
 
-The measurement runs in a worker subprocess so the forced 8-device
-XLA_FLAGS never leak into the caller (benchmarks/run.py --smoke runs in a
-1-device process by design).  Writes BENCH_dist_goss.json for the
-cross-PR perf trajectory.  ``--gate`` is the blocking CI mode: it re-runs
+The measurement runs in a worker subprocess on the CPU
+(``JAX_PLATFORMS=cpu``) so the forced 8-device XLA_FLAGS never leak into
+the caller (benchmarks/run.py --smoke runs in a 1-device process by
+design) and the worker never competes for an accelerator.  Writes
+BENCH_dist_goss.json for the cross-PR perf trajectory.  ``--gate`` is the blocking CI mode: it re-runs
 the smoke shapes into a throwaway path (no self-ratcheting, same rule as
 the other gates) and exits nonzero when the sharded scatter-work ratio
 drops below the 2x floor / materially below the committed baseline, the
@@ -148,6 +149,9 @@ def _run_worker(shapes: dict) -> dict:
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    # the worker counts on forced host devices; on a machine with a chip
+    # it must never compete with a parent that already holds the chip
+    env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = os.pathsep.join(
         [os.path.join(root, "src"), root] +
         ([env["PYTHONPATH"]] if env.get("PYTHONPATH") else []))

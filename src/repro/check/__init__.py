@@ -6,7 +6,7 @@ steady-state serve recompiles) is a claim about what the *traced program*
 contains.  This package certifies those claims without running anything:
 
 * :mod:`repro.check.walker` — ONE canonical recursive jaxpr walker
-  (pjit / scan / while / cond / custom-vjp / shard_map / pallas_call
+  (jit / scan / while / cond / custom-vjp / shard_map / pallas_call
   sub-jaxprs all handled), replacing the divergent hand-rolled copies
   that used to live in individual tests.
 * :mod:`repro.check.rules` — reusable rule classes (collective budgets,

@@ -7,7 +7,7 @@ inline ``prim_names``) that each handled a different subset of the
 sub-jaxpr containers jax uses.  This module handles them all, generically:
 a sub-jaxpr can hide in any ``eqn.params`` value as a ``Jaxpr``, a
 ``ClosedJaxpr``, or arbitrarily nested inside lists / tuples / dicts —
-which covers ``pjit``, ``scan``, ``while`` (cond + body), ``cond``
+which covers ``jit``, ``scan``, ``while`` (cond + body), ``cond``
 (branch list), ``custom_jvp_call`` / ``custom_vjp_call``, ``shard_map``,
 ``pallas_call`` (``grid_mapping`` holds the kernel jaxpr), ``remat``,
 and whatever jax adds next, without naming any of them.
@@ -22,9 +22,7 @@ __all__ = ["iter_eqns", "prim_names", "collect_avals", "sub_jaxprs"]
 def _as_jaxpr(obj: Any):
     """Return the open ``Jaxpr`` held by ``obj``, or None.
 
-    Duck-typed on purpose: ``jax.core`` moved/renamed these classes across
-    the 0.4.x → 0.5+ window, and the walker must not import any private
-    jax module to stay compatible with both CI matrix legs."""
+    Duck-typed on purpose, so the walker imports no private jax module."""
     name = type(obj).__name__
     if name == "ClosedJaxpr":
         return obj.jaxpr
@@ -71,7 +69,7 @@ def iter_eqns(jaxpr, *, enter_pallas: bool = True) -> Iterator[Any]:
 # wrapper primitives whose *name* is trace plumbing, not computation —
 # excluded from prim_names sequences so that "same primitives" comparisons
 # are insensitive to how many jit boundaries wrap a function
-TRANSPARENT_PRIMS = frozenset({"pjit", "closed_call", "custom_jvp_call",
+TRANSPARENT_PRIMS = frozenset({"jit", "closed_call", "custom_jvp_call",
                                "custom_vjp_call", "remat", "remat2"})
 
 

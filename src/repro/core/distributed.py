@@ -66,8 +66,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro import compat
-from repro.compat import shard_map_norep
 from repro.core.binning import BinnedTable
 from repro.core.tree import (Tree, TreeConfig, _auto_chunk_slots, _chunk_step,
                              _chunk_step_classes, _grow, _grow_batched,
@@ -239,8 +237,8 @@ def make_sharded_step(mesh: Mesh, dist: DistConfig, kw: dict, num_slots: int,
     if weighted:
         in_specs = in_specs + (rspec,)               # sample weights
     out_specs = (rep, rep, hspec if want_hist else rep)
-    sharded = shard_map_norep(body, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs)
+    sharded = jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                            out_specs=out_specs, check_vma=False)
     fn = jax.jit(sharded)
     return _cache_put(_STEP_CACHE, cache_key, fn)
 
@@ -262,8 +260,8 @@ def make_sharded_route(mesh: Mesh, dist: DistConfig, classes: int = 0):
     rspec = P(None, dist.data_axes) if classes else P(dist.data_axes)
     in_specs = (P(dist.data_axes, dist.model_axis), rspec,
                 P(), P(dist.model_axis), P(), P())
-    fn = jax.jit(shard_map_norep(body, mesh=mesh, in_specs=in_specs,
-                                 out_specs=rspec))
+    fn = jax.jit(jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                               out_specs=rspec, check_vma=False))
     return _cache_put(_ROUTE_CACHE, cache_key, fn)
 
 
@@ -272,7 +270,7 @@ def _data_shard_index(data_axes):
     matching the contiguous row-block layout of ``P(data_axes)``)."""
     idx = jnp.int32(0)
     for ax in data_axes:
-        idx = idx * compat.axis_size(ax) + jax.lax.axis_index(ax)
+        idx = idx * jax.lax.axis_size(ax) + jax.lax.axis_index(ax)
     return idx
 
 
@@ -362,9 +360,9 @@ def make_sharded_sampler(mesh: Mesh, dist: DistConfig, loss, goss,
             return sample(y, raw, key, None)
         in_specs = (dspec, rspec, P())
 
-    fn = jax.jit(shard_map_norep(
+    fn = jax.jit(jax.shard_map(
         body, mesh=mesh, in_specs=in_specs,
-        out_specs=(rspec, rspec, rspec)))
+        out_specs=(rspec, rspec, rspec), check_vma=False))
     return _cache_put(_SAMPLER_CACHE, cache_key, fn)
 
 
@@ -417,8 +415,8 @@ def make_sharded_walk(mesh: Mesh, dist: DistConfig, num_steps: int,
 
     in_specs = (rspec, P(), P(dist.data_axes, dist.model_axis),
                 P(dist.model_axis), P())
-    fn = jax.jit(shard_map_norep(body, mesh=mesh, in_specs=in_specs,
-                                 out_specs=rspec))
+    fn = jax.jit(jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                               out_specs=rspec, check_vma=False))
     return _cache_put(_WALK_CACHE, cache_key, fn)
 
 
@@ -459,8 +457,8 @@ def make_sharded_grid_counts(mesh: Mesh, dist: DistConfig, *,
     in_specs = (dspec, dspec, dspec, dspec, dspec,
                 P(dist.model_axis), P(), P())
     out_specs = P(None, dist.model_axis, None)
-    fn = jax.jit(shard_map_norep(body, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs))
+    fn = jax.jit(jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                               out_specs=out_specs, check_vma=False))
     return _cache_put(_GRID_CACHE, cache_key, fn)
 
 

@@ -74,16 +74,20 @@ def _segment_backend(bins, stats, slot, num_slots, n_bins, weights=None):
     m, k = bins.shape
     stats = _weighted(stats, weights)
     c = stats.shape[-1]
-    base = slot * n_bins                                   # [M]
-    idx = base[:, None] + bins                             # [M, K]
+    sb = num_slots * n_bins
+    # ONE scalar scatter over every (feature, channel, example) triple.  A
+    # scatter of [M, C] rows (the channel as the update window) is laid out
+    # on a TPU with C padded to 128 lanes: at KDD99-10% width that scatter
+    # alone needs ~10 GB of HBM, and the class-batched multiclass step
+    # ~50 GB.  Flat scalar updates keep the layout dense.
+    seg = (jnp.arange(k * c, dtype=jnp.int32).reshape(k, c, 1) * sb
+           + slot * n_bins + bins.T[:, None, :])           # [K, C, M]
     # invalid slots (< 0) become out-of-range -> dropped by scatter semantics
-    idx = jnp.where(slot[:, None] < 0, -1, idx)
-
-    def per_feature(col_idx):
-        return jax.ops.segment_sum(stats, col_idx, num_segments=num_slots * n_bins)
-
-    h = jax.vmap(per_feature, in_axes=1, out_axes=0)(idx)  # [K, S*B, C]
-    return h.reshape(k, num_slots, n_bins, c).transpose(1, 0, 2, 3)
+    seg = jnp.where(slot < 0, -1, seg)
+    upd = jnp.broadcast_to(stats.T[None], (k, c, m))
+    h = jax.ops.segment_sum(upd.reshape(-1), seg.reshape(-1),
+                            num_segments=k * c * sb)       # [K*C*S*B]
+    return h.reshape(k, c, num_slots, n_bins).transpose(2, 0, 3, 1)
 
 
 def _onehot_backend(bins, stats, slot, num_slots, n_bins, weights=None):
@@ -132,6 +136,14 @@ def node_histogram(bins: jax.Array, stats: jax.Array, slot: jax.Array, *,
     return _BACKENDS[backend](bins, stats, slot, num_slots, n_bins, weights)
 
 
+def _packed_slot(slot, compute, num_slots):
+    """Raw slot ids -> packed pair ids: the computed child of pair ``j``
+    (slots ``2j``, ``2j+1``) maps to ``j``; every other row maps to -1."""
+    slot_map = jnp.where(compute, jnp.arange(num_slots, dtype=jnp.int32) // 2,
+                         -1)
+    return jnp.where(slot >= 0, slot_map[jnp.clip(slot, 0, num_slots - 1)], -1)
+
+
 @functools.partial(jax.jit, static_argnames=("num_slots", "n_bins", "backend"))
 def node_histogram_smaller_child(bins: jax.Array, stats: jax.Array,
                                  slot: jax.Array, compute: jax.Array, *,
@@ -160,19 +172,9 @@ def node_histogram_smaller_child(bins: jax.Array, stats: jax.Array,
     """
     if num_slots % 2:
         raise ValueError("pair packing needs an even slot count")
-    slot_map = jnp.where(compute, jnp.arange(num_slots, dtype=jnp.int32) // 2,
-                         -1)
-    if backend == "pallas":
-        from repro.kernels import ops as kops
-        # in-kernel remap: the [M] slot vector is never rewritten in HBM and
-        # skipped slots occupy no VMEM (the output block is the packed axis).
-        return kops.histogram(bins, stats, slot, num_slots=num_slots // 2,
-                              n_bins=n_bins, slot_map=slot_map,
-                              weights=weights)
-    packed = jnp.where(slot >= 0,
-                       slot_map[jnp.clip(slot, 0, num_slots - 1)], -1)
-    return _BACKENDS[backend](bins, stats, packed, num_slots // 2, n_bins,
-                              weights)
+    return _BACKENDS[backend](bins, stats,
+                              _packed_slot(slot, compute, num_slots),
+                              num_slots // 2, n_bins, weights)
 
 
 @functools.partial(jax.jit, static_argnames=("num_slots", "n_bins", "backend"))
@@ -206,10 +208,9 @@ def node_histogram_sibling_fused(bins: jax.Array, stats: jax.Array,
     small_is_left = compute[0::2]                            # [pairs]
     if backend == "pallas":
         from repro.kernels import ops as kops
-        slot_map = jnp.where(compute,
-                             jnp.arange(num_slots, dtype=jnp.int32) // 2, -1)
-        return kops.histogram(bins, stats, slot, num_slots=num_slots // 2,
-                              n_bins=n_bins, slot_map=slot_map,
+        return kops.histogram(bins, stats,
+                              _packed_slot(slot, compute, num_slots),
+                              num_slots=num_slots // 2, n_bins=n_bins,
                               phist=phist_pairs, side=small_is_left,
                               weights=weights)
     h_small = node_histogram_smaller_child(bins, stats, slot, compute,

@@ -135,13 +135,21 @@ def best_splits_kernel(hist: jax.Array, n_num: jax.Array, n_cat: jax.Array, *,
     """Kernel-backed selection: the fused Pallas split-scan produces the best
     candidate per (slot, feature); the tiny cross-feature argmax happens
     here.  pos/neg child stats are not materialised (the tree builder derives
-    child statistics at the child's own level)."""
+    child statistics at the child's own level).
+
+    Ties break as in ``best_splits``' flat op-major argmax: among the
+    features whose best score is the maximum, the lowest (op, feature)
+    wins.  Each feature's own (op, bin) is already the lowest among its
+    maxima, so both backends pick the same split."""
     from repro.kernels import ops as kops
     score_kf, bin_kf, op_kf = kops.split_scan(hist, n_num, n_cat,
                                               heuristic=heuristic,
                                               min_leaf=min_leaf)
     s, k = score_kf.shape
-    feat = jnp.argmax(score_kf, axis=1).astype(jnp.int32)
+    top = score_kf.max(axis=1, keepdims=True)
+    key = jnp.where(score_kf >= top,
+                    op_kf * k + jnp.arange(k, dtype=jnp.int32), 3 * k)
+    feat = jnp.argmin(key, axis=1).astype(jnp.int32)
     take = lambda a: jnp.take_along_axis(a, feat[:, None], axis=1)[:, 0]
     c = hist.shape[-1]
     zeros = jnp.zeros((s, c), dtype=hist.dtype)

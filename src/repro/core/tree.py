@@ -31,8 +31,8 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro import compat
 from repro.core import split as split_mod
 from repro.core.binning import BinnedTable
 from repro.core.histogram import (node_histogram,
@@ -286,7 +286,7 @@ def _chunk_step_impl(bins, stats, lbins, y, assign, arrays, phist_pairs, n_num,
         # distributed build reproduces the local tree bit-for-bit —
         # histogram counts are integers, hence psum-order independent.
         my = jax.lax.axis_index(model_axis)
-        n_shards = compat.axis_size(model_axis)
+        n_shards = jax.lax.axis_size(model_axis)
         k_tot = k_local * n_shards
         feat_g = dec.feat + my * k_local
         flat_idx = (dec.op * k_tot + feat_g) * n_bins + dec.bin   # global order
@@ -357,7 +357,7 @@ def _chunk_step_impl(bins, stats, lbins, y, assign, arrays, phist_pairs, n_num,
             per = h_small.shape[0]
             idx = jnp.int32(0)
             for ax in data_axes:
-                idx = idx * compat.axis_size(ax) + jax.lax.axis_index(ax)
+                idx = idx * jax.lax.axis_size(ax) + jax.lax.axis_index(ax)
             sl = jax.lax.dynamic_slice(small_is_left, (idx * per,), (per,))
         else:
             h_small = reduce_data(h_small)                   # psum [s/2,...]
@@ -628,6 +628,22 @@ def _subtract_eligible(config: TreeConfig, m: int,
             and m < 1 << 24)
 
 
+def _take_rows(x, idx, axis=0):
+    """``x[idx]`` along ``axis`` (an index array or a slice).
+
+    On a mesh with ``Explicit`` axes (the ``jax.make_mesh`` default) the
+    level caches arrive sharded along their row axis, and a row gather or
+    an uneven row slice has no unambiguous output sharding.  The result is
+    therefore replicated along ``axis`` and keeps ``x``'s sharding on every
+    other axis (the sharded level step's in_specs reshard it).  Off a mesh
+    this is plain indexing."""
+    sh = jax.typeof(x).sharding
+    spec = list(sh.spec) + [None] * (x.ndim - len(sh.spec))
+    spec[axis] = None
+    key = (slice(None),) * axis + (idx,)
+    return x.at[key].get(out_sharding=NamedSharding(sh.mesh, P(*spec)))
+
+
 def _parent_rows(parent, cache, cs, s):
     """Gather each sibling pair's parent histogram row for one level chunk.
 
@@ -639,7 +655,7 @@ def _parent_rows(parent, cache, cs, s):
                    jnp.int32(cs) + jnp.arange(0, s, 2, dtype=jnp.int32),
                    mode="fill", fill_value=-1)
     idx = jnp.clip(pid - base, 0, hist.shape[0] - 1)
-    return hist[idx]
+    return _take_rows(hist, idx)
 
 
 def _grow(step, route, arrays, assign, s_cap, max_nodes, level_callback,
@@ -688,7 +704,8 @@ def _grow(step, route, arrays, assign, s_cap, max_nodes, level_callback,
             next_free += int(n_children)
             if want:
                 hists.append(h)
-        cache = ((level_start, jnp.concatenate(hists, axis=0)[:width])
+        cache = ((level_start,
+                  _take_rows(jnp.concatenate(hists, axis=0), slice(0, width)))
                  if want else None)
         assign = route(assign, arrays, level_start, level_end)
         level_start, level_end = level_end, next_free
@@ -768,7 +785,8 @@ def _grow_batched(step, route, arrays, assign, s_cap, max_nodes,
             if want:
                 hists.append(h)
         cache = ((level_start.copy(),
-                  jnp.concatenate(hists, axis=1)[:, :wmax])
+                  _take_rows(jnp.concatenate(hists, axis=1),
+                             slice(0, wmax), axis=1))
                  if want else None)
         assign = route(assign, arrays, level_start, level_end)
         level_start, level_end = level_end, next_free.copy()

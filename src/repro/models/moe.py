@@ -16,7 +16,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro import compat
 import numpy as np
 
 from repro.models.layers import ffn_block, init_ffn, truncated_normal
@@ -60,7 +59,7 @@ def _moe_a2a_experts(x, router, w_gate, w_up, w_down, *, cfg, model_axis):
     b, t, d = x.shape
     n = b * t
     e, k = cfg.n_experts, cfg.top_k
-    msize = compat.axis_size(model_axis)
+    msize = jax.lax.axis_size(model_axis)
     e_loc = e // msize
     my = jax.lax.axis_index(model_axis)
     n_loc = n // msize
@@ -102,7 +101,7 @@ def _moe_local_experts(x, router, w_gate, w_up, w_down, *, cfg, model_axis):
     b, t, d = x.shape
     n = b * t
     e, k = cfg.n_experts, cfg.top_k
-    msize = compat.axis_size(model_axis)
+    msize = jax.lax.axis_size(model_axis)
     e_loc = e // msize
     xf = x.reshape(n, d)
     cap = int(np.ceil(k * n / e * cfg.moe_capacity_factor))
@@ -139,10 +138,10 @@ def moe_block(p, x, cfg):
             xx, r, wg, wu, wd, cfg=cfg, model_axis=axes.model)
         dspec = P(axes.data, None, None)
         espec = P(axes.model, None, None)
-        out = compat.shard_map_norep(
+        out = jax.shard_map(
             body, mesh=SH.MESH,
             in_specs=(dspec, P(), espec, espec, espec),
-            out_specs=dspec,
+            out_specs=dspec, check_vma=False,
         )(x, p["router"], p["w_gate"].astype(x.dtype),
           p["w_up"].astype(x.dtype), p["w_down"].astype(x.dtype))
         if cfg.moe_dense_residual:

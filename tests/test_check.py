@@ -31,7 +31,6 @@ from repro.check import (BANNED_GATHER_PRIMS, COLLECTIVE_PRIMS,
                          NoDynamicShapes, NoHostTransfer, ScratchBudget,
                          Surface, iter_eqns, prim_names)
 from repro.check.walker import TRANSPARENT_PRIMS
-from repro.compat import shard_map_norep
 
 
 # -- walker ----------------------------------------------------------------
@@ -154,8 +153,8 @@ def _mesh1():
 
 def _sharded_surface(body, x):
     from jax.sharding import PartitionSpec as P
-    fn = shard_map_norep(body, mesh=_mesh1(), in_specs=P("data"),
-                         out_specs=P())
+    fn = jax.shard_map(body, mesh=_mesh1(), in_specs=P("data"),
+                       out_specs=P(), check_vma=False)
     return Surface(jaxpr=jax.make_jaxpr(fn)(x), label="test")
 
 

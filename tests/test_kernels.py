@@ -14,6 +14,7 @@ SHAPES = [
     (128, 3, 33, 2, 9),       # odd bins, slot count > slot_chunk
     (1000, 2, 8, 26, 3),      # many classes
     (37, 7, 5, 3, 2),         # M not divisible by tile
+    (200, 11, 6, 3, 4),       # K past one feature block (padded features)
 ]
 
 
@@ -106,12 +107,17 @@ def _mk_fused(m, k, b, c, pairs, seed=0):
     return bins, stats, slot, slot_map, phist, jnp.asarray(1 - side)
 
 
+def _remap(slot, slot_map):
+    """Raw slot ids -> packed pair ids, as the builder hands the kernel."""
+    return jnp.where(slot >= 0, slot_map[jnp.clip(slot, 0, None)], -1)
+
+
 @pytest.mark.parametrize("m,k,b,c,p", SHAPES)
 def test_histogram_fused_sibling_matches_ref(m, k, b, c, p):
     bins, stats, slot, slot_map, phist, side = _mk_fused(m, k, b, c, p,
                                                          seed=p)
-    got = ops.histogram(bins, stats, slot, num_slots=p, n_bins=b,
-                        slot_map=slot_map, phist=phist, side=side)
+    got = ops.histogram(bins, stats, _remap(slot, slot_map), num_slots=p,
+                        n_bins=b, phist=phist, side=side)
     want = ref.sibling_ref(bins, stats, slot, slot_map, phist, side,
                            num_pairs=p, n_bins=b)
     assert got.shape == (2 * p, k, b, c)
@@ -126,10 +132,9 @@ def test_histogram_fused_sibling_tile_invariance(tile, slot_chunk):
     m, k, b, c, p = 300, 3, 7, 4, 6
     bins, stats, slot, slot_map, phist, side = _mk_fused(m, k, b, c, p,
                                                          seed=5)
-    got = histogram_pallas(bins, stats, slot, num_slots=p, n_bins=b,
-                           slot_chunk=slot_chunk, example_tile=tile,
-                           interpret=True, slot_map=slot_map, phist=phist,
-                           side=side)
+    got = histogram_pallas(bins, stats, _remap(slot, slot_map), num_slots=p,
+                           n_bins=b, slot_chunk=slot_chunk, example_tile=tile,
+                           interpret=True, phist=phist, side=side)
     want = ref.sibling_ref(bins, stats, slot, slot_map, phist, side,
                            num_pairs=p, n_bins=b)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
@@ -146,3 +151,26 @@ def test_property_histogram_random_shapes(m, k, b, c, s, seed):
     assert got.shape == (s, k, b, c)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-5, atol=1e-5)
+
+
+def test_importing_repro_starts_no_backend():
+    """The kernels pick interpret vs compiled mode when called, so no
+    module of the package (``repro.kernels.ops`` and ``repro.check``
+    included) starts a JAX backend as it is imported."""
+    import os
+    import subprocess
+    import sys
+    script = (
+        "import importlib, pkgutil, repro\n"
+        "from jax._src import xla_bridge\n"
+        "for m in pkgutil.walk_packages(repro.__path__, 'repro.'):\n"
+        "    if not m.name.endswith('__main__'):\n"
+        "        importlib.import_module(m.name)\n"
+        "    assert not xla_bridge.backends_are_initialized(), m.name\n"
+        "print('NO_BACKEND_OK')\n")
+    env = dict(os.environ)
+    env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
+    r = subprocess.run([sys.executable, "-c", script], env=env,
+                       capture_output=True, text=True, timeout=300)
+    assert r.returncode == 0, r.stdout + "\n" + r.stderr
+    assert "NO_BACKEND_OK" in r.stdout

@@ -107,7 +107,7 @@ def test_smaller_child_pallas_matches_segment():
     bins, stats, slot, _ = _random_pair_case(rng, 300, 4, 3, 9, 3,
                                              skew=0.3, empty_frac=0.25,
                                              kind="class")
-    # mixed left/right computed slots so the in-kernel remap is exercised
+    # mixed left/right computed slots so the slot remap is exercised
     # at both even and odd source slots (the ~small_is_left case)
     compute = jnp.asarray([True, False, False, True, False, True, True,
                            False])
