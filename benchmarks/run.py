@@ -11,9 +11,7 @@ CI driver: it runs EVERY registered bench gate (each still runnable
 standalone via ``python -m benchmarks.bench_<name> --gate``), prints one
 per-gate pass/fail table — appended to ``$GITHUB_STEP_SUMMARY`` when set —
 and exits nonzero if any gate fails, so the workflow needs exactly one
-blocking step instead of one copy-pasted step per gate.  Roofline rows are
-appended if experiments/dryrun.json exists (run launch/dryrun.py to
-regenerate)."""
+blocking step instead of one copy-pasted step per gate."""
 from __future__ import annotations
 
 import os
@@ -174,14 +172,6 @@ def main() -> None:
     if not smoke:
         print("# kernel micro-bench")
         bench_kernels.main()
-
-    if os.path.exists("experiments/dryrun.json"):
-        print("# roofline (from experiments/dryrun.json)")
-        from benchmarks import roofline
-        roofline.main("experiments/dryrun.json")
-    else:
-        print("# roofline: experiments/dryrun.json missing — run "
-              "PYTHONPATH=src python -m repro.launch.dryrun first")
 
 
 if __name__ == "__main__":

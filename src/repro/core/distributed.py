@@ -66,6 +66,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from repro.core import obs
 from repro.core.binning import BinnedTable
 from repro.core.tree import (Tree, TreeConfig, _auto_chunk_slots, _chunk_step,
                              _chunk_step_classes, _grow, _grow_batched,
@@ -487,6 +488,13 @@ def sharded_grid_counts(mesh: Mesh, dist: DistConfig, lab, cnt, cmc, y,
     return np.asarray(out)[:, :ns, :]
 
 
+def _put(x: np.ndarray, sharding):
+    """A host array placed on the mesh, in a ``udt.put`` span that counts
+    its bytes."""
+    with obs.counted("udt.put", "h2d_bytes", x.nbytes, bytes=x.nbytes):
+        return jax.device_put(x, sharding)
+
+
 class DistributedBuilder:
     """Stage a BinnedTable on the mesh once; build many trees from it.
 
@@ -573,9 +581,8 @@ class DistributedBuilder:
             # must not flow into the f32 moment channels); identity — the
             # same array object — when the dtype already agrees.
             return jax.device_put(x.astype(dtype), self._rows)
-        return jax.device_put(
-            _pad_to(np.asarray(x, dtype), self.d_shards, 0, fill),
-            self._rows)
+        return _put(_pad_to(np.asarray(x, dtype), self.d_shards, 0, fill),
+                    self._rows)
 
     def _stage_class_rows(self, x, fill, dtype):
         """Shard a class-first [C, m] matrix over the data axes with the
@@ -586,9 +593,10 @@ class DistributedBuilder:
         spec = NamedSharding(self.mesh, P(None, self.dist.data_axes))
         if isinstance(x, jax.Array) and x.shape[-1] == self.m_pad:
             return jax.device_put(x.astype(dtype), spec)
-        return jax.device_put(
-            _pad_to(np.asarray(x, dtype), self.d_shards, 1, fill), spec)
+        return _put(_pad_to(np.asarray(x, dtype), self.d_shards, 1, fill),
+                    spec)
 
+    @obs.spanned("udt.tree")
     def build(self, y, sample_weight=None, assign=None,
               level_callback=None) -> Tree:
         """Build one tree.  ``y`` / ``sample_weight`` / ``assign`` are host
@@ -616,7 +624,7 @@ class DistributedBuilder:
                if weighted else None)
         assign_d = (self._stage_rows(assign, -1, np.int32)
                     if assign is not None
-                    else jax.device_put(self._assign0, self._rows))
+                    else _put(self._assign0, self._rows))
 
         kw = dict(n_bins=self.b, heuristic=config.heuristic, task=config.task,
                   min_samples_split=config.min_samples_split,
@@ -661,6 +669,7 @@ class DistributedBuilder:
                                 max_depth=config.max_depth)
         return Tree(n_nodes=n_nodes, **arrays)
 
+    @obs.spanned("udt.tree")
     def build_batched(self, z, sample_weight=None, assign=None,
                       level_callback=None):
         """Build one ``regression_variance`` tree per row of ``z`` [C, m]

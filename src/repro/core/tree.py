@@ -33,6 +33,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from repro.core import obs
 from repro.core import split as split_mod
 from repro.core.binning import BinnedTable
 from repro.core.histogram import (node_histogram,
@@ -308,9 +309,15 @@ def _chunk_step_impl(bins, stats, lbins, y, assign, arrays, phist_pairs, n_num,
         return split_mod.SplitDecision(
             pick(0), pick(1).astype(jnp.int32), pick(2).astype(jnp.int32),
             pick(3).astype(jnp.int32), dec.pos_stats, dec.neg_stats), pick(5)
-    slot_of_node = assign - chunk_start
-    slot = jnp.where((slot_of_node >= 0) & (slot_of_node < chunk_n),
-                     slot_of_node, -1)
+    # the named scopes put each op's device time down to a part of the
+    # step in a profiler trace (its ``tf_op`` path); they change no op
+    histogram_scope = jax.named_scope("level.histogram")
+    select_scope = jax.named_scope("level.select")
+    update_scope = jax.named_scope("level.update")
+    with histogram_scope:
+        slot_of_node = assign - chunk_start
+        slot = jnp.where((slot_of_node >= 0) & (slot_of_node < chunk_n),
+                         slot_of_node, -1)
     slot_ids = jnp.arange(s, dtype=jnp.int32)
     in_chunk = slot_ids < chunk_n
     node_ids = jnp.where(in_chunk, chunk_start + slot_ids, max_nodes)
@@ -373,84 +380,101 @@ def _chunk_step_impl(bins, stats, lbins, y, assign, arrays, phist_pairs, n_num,
 
     if task == "regression":
         # Algorithm 6: per-node label split -> per-example pseudo class.
-        lhist = reduce_data(node_histogram(
-            lbins[:, None], moment_stats(y), slot, num_slots=s,
-            n_bins=n_label_bins, backend=hist_backend)[:, 0])       # [S,Bl,3]
-        tstar, mean, count_f, sse = _label_split_thresholds(lhist)
-        tstar, label, count_f, sse = regather((tstar, mean, count_f, sse))
-        pseudo = (lbins <= tstar[jnp.clip(slot, 0, s - 1)]).astype(jnp.int32)
-        stats = class_stats(pseudo, 2)
-        count = jnp.round(count_f).astype(jnp.int32)
-        pure = sse <= 1e-10 * jnp.maximum(count_f, 1.0)
-        hist = build_hist(stats)
-        dec, mc = select(hist, n_num, n_cat, heuristic=heuristic,
-                         min_leaf=min_samples_leaf)
-        dec, mc = regather((dec, mc))
+        with histogram_scope:
+            lhist = reduce_data(node_histogram(
+                lbins[:, None], moment_stats(y), slot, num_slots=s,
+                n_bins=n_label_bins, backend=hist_backend)[:, 0])   # [S,Bl,3]
+            tstar, mean, count_f, sse = _label_split_thresholds(lhist)
+            tstar, label, count_f, sse = regather((tstar, mean, count_f,
+                                                   sse))
+            pseudo = (lbins <= tstar[jnp.clip(slot, 0, s - 1)]).astype(
+                jnp.int32)
+            stats = class_stats(pseudo, 2)
+        with update_scope:
+            count = jnp.round(count_f).astype(jnp.int32)
+            pure = sse <= 1e-10 * jnp.maximum(count_f, 1.0)
+        with histogram_scope:
+            hist = build_hist(stats)
+        with select_scope:
+            dec, mc = select(hist, n_num, n_cat, heuristic=heuristic,
+                             min_leaf=min_samples_leaf)
+            dec, mc = regather((dec, mc))
     elif task == "regression_variance":
-        hist = build_hist(moment_stats(y))
-        tot = hist[:, 0].sum(axis=1)                                # [S,3]
-        count_f = tot[:, 0]
-        safe = jnp.where(count_f > 0, count_f, 1.0)
-        label = tot[:, 1] / safe
-        count = jnp.round(count_f).astype(jnp.int32)
-        pure = (tot[:, 2] - tot[:, 1] ** 2 / safe) <= 1e-10 * jnp.maximum(count_f, 1.0)
-        dec, mc = select(hist, n_num, n_cat, heuristic="sse",
-                         min_leaf=min_samples_leaf)
-        count, label, pure, dec, mc = regather((count, label, pure, dec, mc))
+        with histogram_scope:
+            hist = build_hist(moment_stats(y))
+        with update_scope:
+            tot = hist[:, 0].sum(axis=1)                            # [S,3]
+            count_f = tot[:, 0]
+            safe = jnp.where(count_f > 0, count_f, 1.0)
+            label = tot[:, 1] / safe
+            count = jnp.round(count_f).astype(jnp.int32)
+            pure = ((tot[:, 2] - tot[:, 1] ** 2 / safe)
+                    <= 1e-10 * jnp.maximum(count_f, 1.0))
+        with select_scope:
+            dec, mc = select(hist, n_num, n_cat, heuristic="sse",
+                             min_leaf=min_samples_leaf)
+            count, label, pure, dec, mc = regather((count, label, pure, dec,
+                                                    mc))
     else:
-        hist = build_hist(stats)
-        tot = hist[:, 0].sum(axis=1)                                # [S,C]
-        count = jnp.round(tot.sum(-1)).astype(jnp.int32)
-        label = jnp.argmax(tot, axis=-1).astype(jnp.float32)
-        pure = tot.max(-1) == tot.sum(-1)
-        dec, mc = select(hist, n_num, n_cat, heuristic=heuristic,
-                         min_leaf=min_samples_leaf)
-        count, label, pure, dec, mc = regather((count, label, pure, dec, mc))
+        with histogram_scope:
+            hist = build_hist(stats)
+        with update_scope:
+            tot = hist[:, 0].sum(axis=1)                            # [S,C]
+            count = jnp.round(tot.sum(-1)).astype(jnp.int32)
+            label = jnp.argmax(tot, axis=-1).astype(jnp.float32)
+            pure = tot.max(-1) == tot.sum(-1)
+        with select_scope:
+            dec, mc = select(hist, n_num, n_cat, heuristic=heuristic,
+                             min_leaf=min_samples_leaf)
+            count, label, pure, dec, mc = regather((count, label, pure, dec,
+                                                    mc))
 
-    no_split = dec.score <= NEG_INF / 2
-    is_leaf = (in_chunk & (pure | no_split
-                           | (count < min_samples_split)
-                           | (depth >= max_depth)))
-    if min_child_weight:
-        # stopping rule, not a candidate mask: the node keeps its
-        # unconstrained best split but becomes a leaf when that split's
-        # lighter child carries <= min_child_weight (rounded) weight.
-        # mc is garbage where no_split holds — already a leaf there.
-        is_leaf = is_leaf | (in_chunk & (mc <= min_child_weight))
-    wants_split = in_chunk & ~is_leaf
+    with update_scope:
+        no_split = dec.score <= NEG_INF / 2
+        is_leaf = (in_chunk & (pure | no_split
+                               | (count < min_samples_split)
+                               | (depth >= max_depth)))
+        if min_child_weight:
+            # stopping rule, not a candidate mask: the node keeps its
+            # unconstrained best split but becomes a leaf when that split's
+            # lighter child carries <= min_child_weight (rounded) weight.
+            # mc is garbage where no_split holds — already a leaf there.
+            is_leaf = is_leaf | (in_chunk & (mc <= min_child_weight))
+        wants_split = in_chunk & ~is_leaf
 
-    # allocate children; respect the node budget (overflow -> forced leaf)
-    offs = jnp.cumsum(wants_split.astype(jnp.int32)) - 1
-    left = next_free + 2 * offs
-    right = left + 1
-    fits = right < max_nodes
-    is_leaf = is_leaf | (wants_split & ~fits)
-    wants_split = wants_split & fits
-    n_children = 2 * wants_split.sum(dtype=jnp.int32)
+        # allocate children; respect the node budget (overflow -> leaf)
+        offs = jnp.cumsum(wants_split.astype(jnp.int32)) - 1
+        left = next_free + 2 * offs
+        right = left + 1
+        fits = right < max_nodes
+        is_leaf = is_leaf | (wants_split & ~fits)
+        wants_split = wants_split & fits
+        n_children = 2 * wants_split.sum(dtype=jnp.int32)
 
-    left = jnp.where(wants_split, left, -1)
-    right = jnp.where(wants_split, right, -1)
+        left = jnp.where(wants_split, left, -1)
+        right = jnp.where(wants_split, right, -1)
 
-    def upd(name, vals, ids=node_ids):
-        arrays[name] = arrays[name].at[ids].set(vals, mode="drop")
+        def upd(name, vals, ids=node_ids):
+            arrays[name] = arrays[name].at[ids].set(vals, mode="drop")
 
-    # child -> parent back-pointers: next level's sibling subtraction gathers
-    # each pair's parent histogram row through these.
-    for child in (left, right):
-        upd("parent", node_ids, ids=jnp.where(wants_split, child, max_nodes))
+        # child -> parent back-pointers: next level's sibling subtraction
+        # gathers each pair's parent histogram row through these.
+        for child in (left, right):
+            upd("parent", node_ids,
+                ids=jnp.where(wants_split, child, max_nodes))
 
-    upd("feat", jnp.where(wants_split, dec.feat, -1))
-    upd("op", jnp.where(wants_split, dec.op, -1))
-    upd("tbin", jnp.where(wants_split, dec.bin, -1))
-    upd("score", jnp.where(wants_split, dec.score, NEG_INF))
-    upd("label", label)
-    upd("count", count)
-    upd("depth", jnp.full((s,), depth, dtype=jnp.int32))
-    upd("left", left)
-    upd("right", right)
-    upd("leaf", is_leaf)
-    hist_out = hist if want_hist else jnp.zeros((), dtype=jnp.float32)
-    return arrays, n_children, hist_out
+        upd("feat", jnp.where(wants_split, dec.feat, -1))
+        upd("op", jnp.where(wants_split, dec.op, -1))
+        upd("tbin", jnp.where(wants_split, dec.bin, -1))
+        upd("score", jnp.where(wants_split, dec.score, NEG_INF))
+        upd("label", label)
+        upd("count", count)
+        upd("depth", jnp.full((s,), depth, dtype=jnp.int32))
+        upd("left", left)
+        upd("right", right)
+        upd("leaf", is_leaf)
+        hist_out = hist if want_hist else jnp.zeros((), dtype=jnp.float32)
+        return arrays, n_children, hist_out
 
 
 # the jitted form every single-tree builder calls; the batched (multiclass)
@@ -533,14 +557,15 @@ def _node_predicate(bins, f, op, tbin, n_num, model_axis):
 @functools.partial(jax.jit, static_argnames=("model_axis",))
 def _route_step(bins, assign, arrays, n_num, level_start, level_end, *,
                 model_axis=None):
-    node = assign
-    left = arrays["left"][node]
-    active = (node >= level_start) & (node < level_end) & (left >= 0)
-    f = jnp.maximum(arrays["feat"][node], 0)
-    pos = _node_predicate(bins, f, arrays["op"][node], arrays["tbin"][node],
-                          n_num, model_axis)
-    nxt = jnp.where(pos, left, arrays["right"][node])
-    return jnp.where(active, nxt, node)
+    with jax.named_scope("level.route"):
+        node = assign
+        left = arrays["left"][node]
+        active = (node >= level_start) & (node < level_end) & (left >= 0)
+        f = jnp.maximum(arrays["feat"][node], 0)
+        pos = _node_predicate(bins, f, arrays["op"][node],
+                              arrays["tbin"][node], n_num, model_axis)
+        nxt = jnp.where(pos, left, arrays["right"][node])
+        return jnp.where(active, nxt, node)
 
 
 @functools.partial(jax.jit, static_argnames=("model_axis",))
@@ -565,15 +590,17 @@ def _prepare(table: BinnedTable, y, config: TreeConfig,
     """Input prep shared by the local and distributed builders.
 
     ``table.bins`` / ``y`` may be numpy OR jax arrays; the
-    ``regression_variance`` task never touches the host (no label binning,
-    no transfers), which is what lets the boosted-ensemble loop in
-    core.forest hand residuals in as device Arrays tree after tree.  The
-    two paper tasks keep their host-side prep (classification needs the
-    class count, label-split regression pre-bins the labels once)."""
+    ``regression_variance`` task does no host work (no label binning; a
+    host ``y`` goes to the device with the caller's other inputs), which
+    is what lets the boosted-ensemble loop in core.forest hand residuals
+    in as device Arrays tree after tree.  The two paper tasks keep their
+    host-side prep (classification needs the class count, label-split
+    regression pre-bins the labels once)."""
     bins = table.bins
     m, k = bins.shape
     if config.task == "regression_variance":
-        yv = jnp.asarray(y, dtype=jnp.float32)
+        yv = (jnp.asarray(y, dtype=jnp.float32) if isinstance(y, jax.Array)
+              else np.asarray(y, dtype=np.float32))
         # stats / lbins are dead operands for this task (the moment rows are
         # formed from yv inside the level step); zeros keep the jit
         # signature uniform and cost one deferred fill each.
@@ -673,7 +700,10 @@ def _grow(step, route, arrays, assign, s_cap, max_nodes, level_callback,
     each level's full histogram is cached (unless wider than
     ``budget / row_bytes`` slots) and the next level scatters only the
     smaller child of each split pair.  ``cache`` seeds the parent-level
-    histogram when resuming."""
+    histogram when resuming.
+
+    Each level is a ``udt.level`` span (``depth``, ``width``, ``slots``)
+    and each read of a chunk's child count a ``udt.sync`` span."""
     level_start, level_end, next_free, depth = cursors
     while level_start < level_end:
         width = level_end - level_start
@@ -688,33 +718,39 @@ def _grow(step, route, arrays, assign, s_cap, max_nodes, level_callback,
         # degenerate s == 1 fall outside the pair layout.
         if subtract is not None and s % 2 and s > 1:
             s -= 1
-        paired = s % 2 == 0
-        use = (subtract is not None and cache is not None and paired
-               and width % 2 == 0)
-        # depth >= max_depth forces every node here to a leaf, so this
-        # level has no children and caching its histogram would be wasted
-        want = (subtract is not None and paired and depth < max_depth
-                and width * subtract[0] <= subtract[1])
-        hists = []
-        for cs in range(level_start, level_end, s):
-            cn = min(s, level_end - cs)
-            pp = _parent_rows(arrays["parent"], cache, cs, s) if use else None
-            arrays, n_children, h = step(arrays, assign, cs, cn, next_free,
-                                         depth, s, pp, use, want)
-            next_free += int(n_children)
-            if want:
-                hists.append(h)
-        cache = ((level_start,
-                  _take_rows(jnp.concatenate(hists, axis=0), slice(0, width)))
-                 if want else None)
-        assign = route(assign, arrays, level_start, level_end)
-        level_start, level_end = level_end, next_free
-        depth += 1
-        if level_callback is not None:
-            level_callback(BuildState(
-                arrays, assign, level_start, level_end, next_free, depth,
-                cache[1] if cache is not None else None,
-                cache[0] if cache is not None else -1))
+        with obs.span("udt.level", depth=depth, width=width, slots=s):
+            paired = s % 2 == 0
+            use = (subtract is not None and cache is not None and paired
+                   and width % 2 == 0)
+            # depth >= max_depth forces every node here to a leaf, so this
+            # level has no children and caching its histogram would be
+            # wasted
+            want = (subtract is not None and paired and depth < max_depth
+                    and width * subtract[0] <= subtract[1])
+            hists = []
+            for cs in range(level_start, level_end, s):
+                cn = min(s, level_end - cs)
+                pp = (_parent_rows(arrays["parent"], cache, cs, s) if use
+                      else None)
+                arrays, n_children, h = step(arrays, assign, cs, cn,
+                                             next_free, depth, s, pp, use,
+                                             want)
+                with obs.counted("udt.sync", "syncs"):
+                    next_free += int(n_children)
+                if want:
+                    hists.append(h)
+            cache = ((level_start,
+                      _take_rows(jnp.concatenate(hists, axis=0),
+                                 slice(0, width)))
+                     if want else None)
+            assign = route(assign, arrays, level_start, level_end)
+            level_start, level_end = level_end, next_free
+            depth += 1
+            if level_callback is not None:
+                level_callback(BuildState(
+                    arrays, assign, level_start, level_end, next_free, depth,
+                    cache[1] if cache is not None else None,
+                    cache[0] if cache is not None else -1))
     return arrays, next_free
 
 
@@ -756,7 +792,8 @@ def _grow_batched(step, route, arrays, assign, s_cap, max_nodes,
     ``use_sub`` / ``want_hist`` flags are shared across classes; the
     cached level histogram is padded to the widest class and per-class
     garbage rows are dropped by the same out-of-chunk mechanism as the
-    single-tree build."""
+    single-tree build.  Spans as in ``_grow``; ``width`` is the widest
+    class's."""
     level_start = np.zeros(n_stack, dtype=np.int64)
     level_end = np.ones(n_stack, dtype=np.int64)
     next_free = np.ones(n_stack, dtype=np.int64)
@@ -768,37 +805,41 @@ def _grow_batched(step, route, arrays, assign, s_cap, max_nodes,
         s = min(s_cap, max(16, 1 << (wmax - 1).bit_length()))
         if subtract is not None and s % 2 and s > 1:
             s -= 1
-        paired = s % 2 == 0
-        use = (subtract is not None and cache is not None and paired
-               and bool((widths % 2 == 0).all()))
-        want = (subtract is not None and paired and depth < max_depth
-                and wmax * subtract[0] <= subtract[1])
-        hists = []
-        for i in range(0, wmax, s):
-            cs = level_start + i
-            cn = np.clip(level_end - cs, 0, min(s, wmax - i))
-            pp = (_parent_rows_batched(arrays["parent"], cache, cs, s)
-                  if use else None)
-            arrays, n_children, h = step(arrays, assign, cs, cn, next_free,
-                                         depth, s, pp, use, want)
-            next_free = next_free + np.asarray(n_children, dtype=np.int64)
-            if want:
-                hists.append(h)
-        cache = ((level_start.copy(),
-                  _take_rows(jnp.concatenate(hists, axis=1),
-                             slice(0, wmax), axis=1))
-                 if want else None)
-        assign = route(assign, arrays, level_start, level_end)
-        level_start, level_end = level_end, next_free.copy()
-        depth += 1
-        if level_callback is not None:
-            level_callback(BuildState(
-                arrays, assign, level_start, level_end, next_free, depth,
-                cache[1] if cache is not None else None,
-                cache[0] if cache is not None else -1))
+        with obs.span("udt.level", depth=depth, width=wmax, slots=s):
+            paired = s % 2 == 0
+            use = (subtract is not None and cache is not None and paired
+                   and bool((widths % 2 == 0).all()))
+            want = (subtract is not None and paired and depth < max_depth
+                    and wmax * subtract[0] <= subtract[1])
+            hists = []
+            for i in range(0, wmax, s):
+                cs = level_start + i
+                cn = np.clip(level_end - cs, 0, min(s, wmax - i))
+                pp = (_parent_rows_batched(arrays["parent"], cache, cs, s)
+                      if use else None)
+                arrays, n_children, h = step(arrays, assign, cs, cn, next_free,
+                                             depth, s, pp, use, want)
+                with obs.counted("udt.sync", "syncs"):
+                    next_free = next_free + np.asarray(n_children,
+                                                       dtype=np.int64)
+                if want:
+                    hists.append(h)
+            cache = ((level_start.copy(),
+                      _take_rows(jnp.concatenate(hists, axis=1),
+                                 slice(0, wmax), axis=1))
+                     if want else None)
+            assign = route(assign, arrays, level_start, level_end)
+            level_start, level_end = level_end, next_free.copy()
+            depth += 1
+            if level_callback is not None:
+                level_callback(BuildState(
+                    arrays, assign, level_start, level_end, next_free, depth,
+                    cache[1] if cache is not None else None,
+                    cache[0] if cache is not None else -1))
     return arrays, next_free
 
 
+@obs.spanned("udt.tree")
 def build_trees_batched(table: BinnedTable, z, config: TreeConfig,
                         sample_weight=None, assign0=None,
                         level_callback=None):
@@ -826,19 +867,24 @@ def build_trees_batched(table: BinnedTable, z, config: TreeConfig,
     if config.min_child_weight and config.select_backend == "pallas":
         raise ValueError("min_child_weight needs select_backend='jnp' (the "
                          "fused split-scan kernel has no weight floor)")
-    bins = jnp.asarray(table.bins)
+    n = obs.host_bytes(table.bins, z, sample_weight, assign0, table.n_num,
+                       table.n_cat)
+    with obs.counted("udt.put", "h2d_bytes", n, bytes=n):
+        bins = jnp.asarray(table.bins)
+        z = jnp.asarray(z, dtype=jnp.float32)
+        weights = (jnp.asarray(sample_weight, dtype=jnp.float32)
+                   if sample_weight is not None else None)
+        n_num = jnp.asarray(table.n_num)
+        n_cat = jnp.asarray(table.n_cat)
+        if assign0 is not None:
+            assign0 = jnp.asarray(assign0, dtype=jnp.int32)
     m, k = bins.shape
     b = int(table.n_bins)
-    z = jnp.asarray(z, dtype=jnp.float32)
     n_stack = z.shape[0]
-    weights = (jnp.asarray(sample_weight, dtype=jnp.float32)
-               if sample_weight is not None else None)
     # stats / lbins are dead operands for regression_variance (shared,
     # no class axis); see _prepare.
     stats = jnp.zeros((m, 3), jnp.float32)
     lbins = jnp.zeros((m,), jnp.int32)
-    n_num = jnp.asarray(table.n_num)
-    n_cat = jnp.asarray(table.n_cat)
 
     max_nodes = config.max_nodes or min(2 * m + 1, 1 << 22)
     s_cap = config.chunk_slots or _auto_chunk_slots(
@@ -848,8 +894,7 @@ def build_trees_batched(table: BinnedTable, z, config: TreeConfig,
     if assign0 is None:
         assign = jnp.zeros((n_stack, m), dtype=jnp.int32)
     else:
-        assign = jnp.broadcast_to(jnp.asarray(assign0, dtype=jnp.int32),
-                                  (n_stack, m))
+        assign = jnp.broadcast_to(assign0, (n_stack, m))
     subtract = ((k * b * 3 * 4, config.sub_cache_bytes)
                 if _subtract_eligible(config, m, weights is not None)
                 else None)
@@ -890,6 +935,7 @@ def build_trees_batched(table: BinnedTable, z, config: TreeConfig,
     return trees, arrays
 
 
+@obs.spanned("udt.tree")
 def build_tree(table: BinnedTable, y, config: TreeConfig = TreeConfig(),
                n_classes: int | None = None,
                level_callback=None, resume: "BuildState | None" = None,
@@ -924,14 +970,17 @@ def build_tree(table: BinnedTable, y, config: TreeConfig = TreeConfig(),
         table, y, config, n_classes)
     m, k = bins_np.shape
     b = int(table.n_bins)
-    bins = jnp.asarray(bins_np)
-    stats = jnp.asarray(stats_np)
-    lbins = jnp.asarray(lbins_np)
-    yv = jnp.asarray(yv_np)
-    weights = (jnp.asarray(sample_weight, dtype=jnp.float32)
-               if sample_weight is not None else None)
-    n_num = jnp.asarray(table.n_num)
-    n_cat = jnp.asarray(table.n_cat)
+    n = obs.host_bytes(bins_np, stats_np, lbins_np, yv_np, sample_weight,
+                       table.n_num, table.n_cat)
+    with obs.counted("udt.put", "h2d_bytes", n, bytes=n):
+        bins = jnp.asarray(bins_np)
+        stats = jnp.asarray(stats_np)
+        lbins = jnp.asarray(lbins_np)
+        yv = jnp.asarray(yv_np)
+        weights = (jnp.asarray(sample_weight, dtype=jnp.float32)
+                   if sample_weight is not None else None)
+        n_num = jnp.asarray(table.n_num)
+        n_cat = jnp.asarray(table.n_cat)
 
     max_nodes = config.max_nodes or min(2 * m + 1, 1 << 22)
     s_cap = config.chunk_slots or _auto_chunk_slots(
