@@ -4,12 +4,14 @@
 written into the profiler's own trace, beside the device's operations,
 with ``args`` as its stats.  With no trace running it costs one check.
 
-Three process counters, read with ``counters()``, each raised at the
+Four process counters, read with ``counters()``, each raised at the
 same boundary as a span so that a trace and the counters agree:
 
 * ``h2d_bytes``: bytes ``build_tree`` sent from host arrays to the device
   (``udt.put`` spans, their ``bytes`` arg);
 * ``syncs``: device-to-host reads of the level loop (``udt.sync`` spans);
+* ``hist_rows``: rows the level steps' feature histograms took in, read
+  in those same reads (zero-length ``udt.hist`` spans, their ``rows`` arg);
 * ``compiles``: executables compiled or loaded from the persistent cache
   (zero-length ``repro.compile`` spans, their ``fun`` arg the program).
 """
@@ -28,7 +30,7 @@ __all__ = ["span", "spanned", "counted", "host_bytes", "counters"]
 # persistent-cache load, with the compile's duration
 COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 
-_COUNTS = {"h2d_bytes": 0, "syncs": 0, "compiles": 0}
+_COUNTS = {"h2d_bytes": 0, "syncs": 0, "hist_rows": 0, "compiles": 0}
 _LOCK = threading.Lock()     # fits and compiles may run on several threads
 
 span = jax.profiler.TraceAnnotation

@@ -187,9 +187,12 @@ def _chunk_step_impl(bins, stats, lbins, y, assign, arrays, phist_pairs, n_num,
                 weighted=False, min_child_weight=0.0):
     """Process node slots [chunk_start, chunk_start+chunk_n).
 
-    Returns (arrays, n_children, hist).  All shapes static; chunk_start /
-    chunk_n / next_free / depth are dynamic scalars so one compilation
-    serves the whole build.
+    Returns (arrays, counts, hist): ``counts`` is int32[2], the children
+    the chunk allocated and the rows its feature histogram took in (the
+    chunk's rows, or under subtraction those of each pair's smaller child;
+    summed over data shards).  All shapes static; chunk_start / chunk_n /
+    next_free / depth are dynamic scalars so one compilation serves the
+    whole build.
 
     ``use_sub`` enables sibling subtraction: ``phist_pairs`` holds the
     parent histogram of sibling pair ``j = slot // 2`` ([num_slots//2, K, B,
@@ -326,11 +329,15 @@ def _chunk_step_impl(bins, stats, lbins, y, assign, arrays, phist_pairs, n_num,
 
     def build_hist(stats_rows):
         """One level-chunk histogram: full scatter, or smaller-child scatter
-        plus sibling subtraction when the parent cache is available."""
+        plus sibling subtraction when the parent cache is available.  Also
+        returns the rows it histograms, over every data shard."""
         if not use_sub:
+            rows = (slot >= 0).sum(dtype=jnp.int32)
+            for ax in data_axes:
+                rows = jax.lax.psum(rows, ax)
             return reduce_data(node_histogram(
                 bins, stats_rows, slot, num_slots=s, n_bins=n_bins,
-                backend=hist_backend, weights=w))
+                backend=hist_backend, weights=w)), rows
         # per-node routed-example counts decide which child to scatter; the
         # psum makes the argmin globally consistent across data shards.
         cnt = jax.ops.segment_sum(jnp.ones_like(slot, dtype=jnp.float32),
@@ -340,6 +347,7 @@ def _chunk_step_impl(bins, stats, lbins, y, assign, arrays, phist_pairs, n_num,
         small_is_left = cnt[0::2] <= cnt[1::2]               # [s/2]
         compute = jnp.stack([small_is_left, ~small_is_left],
                             axis=1).reshape(s)
+        rows = jnp.where(compute, cnt, 0.0).sum().astype(jnp.int32)
         if not data_axes:
             # single shard: on pallas the subtraction and the pair
             # interleave run in the kernel's epilogue, so the derived
@@ -350,7 +358,7 @@ def _chunk_step_impl(bins, stats, lbins, y, assign, arrays, phist_pairs, n_num,
             # (node_ids == max_nodes there).
             return node_histogram_sibling_fused(
                 bins, stats_rows, slot, compute, phist_pairs, num_slots=s,
-                n_bins=n_bins, backend=hist_backend, weights=w)
+                n_bins=n_bins, backend=hist_backend, weights=w), rows
         h_small = node_histogram_smaller_child(
             bins, stats_rows, slot, compute, num_slots=s, n_bins=n_bins,
             backend=hist_backend, weights=w)                 # [s/2,K,B,C]
@@ -376,7 +384,7 @@ def _chunk_step_impl(bins, stats, lbins, y, assign, arrays, phist_pairs, n_num,
         return jnp.stack([jnp.where(slb, h_small, h_der),
                           jnp.where(slb, h_der, h_small)],
                          axis=1).reshape(2 * h_small.shape[0], k_local,
-                                         n_bins, stats_rows.shape[-1])
+                                         n_bins, stats_rows.shape[-1]), rows
 
     if task == "regression":
         # Algorithm 6: per-node label split -> per-example pseudo class.
@@ -394,14 +402,14 @@ def _chunk_step_impl(bins, stats, lbins, y, assign, arrays, phist_pairs, n_num,
             count = jnp.round(count_f).astype(jnp.int32)
             pure = sse <= 1e-10 * jnp.maximum(count_f, 1.0)
         with histogram_scope:
-            hist = build_hist(stats)
+            hist, rows = build_hist(stats)
         with select_scope:
             dec, mc = select(hist, n_num, n_cat, heuristic=heuristic,
                              min_leaf=min_samples_leaf)
             dec, mc = regather((dec, mc))
     elif task == "regression_variance":
         with histogram_scope:
-            hist = build_hist(moment_stats(y))
+            hist, rows = build_hist(moment_stats(y))
         with update_scope:
             tot = hist[:, 0].sum(axis=1)                            # [S,3]
             count_f = tot[:, 0]
@@ -417,7 +425,7 @@ def _chunk_step_impl(bins, stats, lbins, y, assign, arrays, phist_pairs, n_num,
                                                     mc))
     else:
         with histogram_scope:
-            hist = build_hist(stats)
+            hist, rows = build_hist(stats)
         with update_scope:
             tot = hist[:, 0].sum(axis=1)                            # [S,C]
             count = jnp.round(tot.sum(-1)).astype(jnp.int32)
@@ -449,7 +457,7 @@ def _chunk_step_impl(bins, stats, lbins, y, assign, arrays, phist_pairs, n_num,
         fits = right < max_nodes
         is_leaf = is_leaf | (wants_split & ~fits)
         wants_split = wants_split & fits
-        n_children = 2 * wants_split.sum(dtype=jnp.int32)
+        counts = jnp.stack([2 * wants_split.sum(dtype=jnp.int32), rows])
 
         left = jnp.where(wants_split, left, -1)
         right = jnp.where(wants_split, right, -1)
@@ -474,7 +482,7 @@ def _chunk_step_impl(bins, stats, lbins, y, assign, arrays, phist_pairs, n_num,
         upd("right", right)
         upd("leaf", is_leaf)
         hist_out = hist if want_hist else jnp.zeros((), dtype=jnp.float32)
-        return arrays, n_children, hist_out
+        return arrays, counts, hist_out
 
 
 # the jitted form every single-tree builder calls; the batched (multiclass)
@@ -508,7 +516,7 @@ def _chunk_step_classes(bins, stats, lbins, y, assign, arrays, phist_pairs,
     ``use_sub`` / ``want_hist`` flags common to every lane.  Classes whose
     frontier is exhausted (or shorter than the widest class's) ride along
     with ``chunk_n = 0`` lanes: every slot is out-of-chunk there, all
-    writes drop, and ``n_children`` is 0 — inert by the same mechanism
+    writes drop, and both counts are 0 — inert by the same mechanism
     that drops past-the-end slots in the single-tree step."""
     kw = dict(num_slots=num_slots, n_bins=n_bins, heuristic=heuristic,
               task=task, min_samples_split=min_samples_split,
@@ -691,8 +699,9 @@ def _grow(step, route, arrays, assign, s_cap, max_nodes, level_callback,
     """The level-synchronous queue (paper Algorithm 5), host-driven.
 
     ``step(arrays, assign, cs, cn, next_free, depth, num_slots, phist_pairs,
-    use_sub, want_hist)`` returns (arrays, n_children, hist); ``route(assign,
-    arrays, start, end)`` returns the new per-example node assignment.
+    use_sub, want_hist)`` returns (arrays, counts, hist), ``counts`` the
+    chunk's children and histogram rows; ``route(assign, arrays, start,
+    end)`` returns the new per-example node assignment.
     ``cursors`` resumes a checkpointed build from the start of a level
     (fault tolerance).
 
@@ -702,8 +711,9 @@ def _grow(step, route, arrays, assign, s_cap, max_nodes, level_callback,
     smaller child of each split pair.  ``cache`` seeds the parent-level
     histogram when resuming.
 
-    Each level is a ``udt.level`` span (``depth``, ``width``, ``slots``)
-    and each read of a chunk's child count a ``udt.sync`` span."""
+    Each level is a ``udt.level`` span (``depth``, ``width``, ``slots``),
+    each read of a chunk's counts a ``udt.sync`` span, and after it a
+    zero-length ``udt.hist`` span (``rows``) raises ``hist_rows``."""
     level_start, level_end, next_free, depth = cursors
     while level_start < level_end:
         width = level_end - level_start
@@ -732,11 +742,13 @@ def _grow(step, route, arrays, assign, s_cap, max_nodes, level_callback,
                 cn = min(s, level_end - cs)
                 pp = (_parent_rows(arrays["parent"], cache, cs, s) if use
                       else None)
-                arrays, n_children, h = step(arrays, assign, cs, cn,
-                                             next_free, depth, s, pp, use,
-                                             want)
+                arrays, counts, h = step(arrays, assign, cs, cn, next_free,
+                                         depth, s, pp, use, want)
                 with obs.counted("udt.sync", "syncs"):
-                    next_free += int(n_children)
+                    n_children, rows = (int(v) for v in np.asarray(counts))
+                next_free += n_children
+                with obs.counted("udt.hist", "hist_rows", rows, rows=rows):
+                    pass
                 if want:
                     hists.append(h)
             cache = ((level_start,
@@ -782,7 +794,7 @@ def _grow_batched(step, route, arrays, assign, s_cap, max_nodes,
 
     ``step(arrays, assign, cs, cn, next_free, depth, num_slots, phist_pairs,
     use_sub, want_hist)`` takes ``cs`` / ``cn`` / ``next_free`` as [C] int
-    vectors and returns (arrays, n_children [C], hist [C, s, K, B, C']);
+    vectors and returns (arrays, counts [C, 2], hist [C, s, K, B, C']);
     ``route(assign, arrays, start, end)`` routes every class.
     ``level_callback`` (optional) receives a BuildState whose cursor fields
     are [C] numpy vectors and whose array fields carry the class axis.
@@ -817,11 +829,14 @@ def _grow_batched(step, route, arrays, assign, s_cap, max_nodes,
                 cn = np.clip(level_end - cs, 0, min(s, wmax - i))
                 pp = (_parent_rows_batched(arrays["parent"], cache, cs, s)
                       if use else None)
-                arrays, n_children, h = step(arrays, assign, cs, cn, next_free,
-                                             depth, s, pp, use, want)
+                arrays, counts, h = step(arrays, assign, cs, cn, next_free,
+                                         depth, s, pp, use, want)
                 with obs.counted("udt.sync", "syncs"):
-                    next_free = next_free + np.asarray(n_children,
-                                                       dtype=np.int64)
+                    counts = np.asarray(counts, dtype=np.int64)
+                next_free = next_free + counts[:, 0]
+                rows = int(counts[:, 1].sum())
+                with obs.counted("udt.hist", "hist_rows", rows, rows=rows):
+                    pass
                 if want:
                     hists.append(h)
             cache = ((level_start.copy(),

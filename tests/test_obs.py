@@ -229,3 +229,56 @@ def test_counters_lose_no_update_across_threads():
     finally:
         sys.setswitchinterval(switch)
     assert obs.counters()["syncs"] - before == 8 * 2_000
+
+
+def least_rows(tree):
+    """``bench/counts.py``'s rule: the root's rows, then the smaller child
+    of every split pair (the larger is its parent's histogram minus it)."""
+    count = np.asarray(tree.count)[:tree.n_nodes]
+    left = np.asarray(tree.left)[:tree.n_nodes]
+    right = np.asarray(tree.right)[:tree.n_nodes]
+    split = np.flatnonzero(left >= 0)
+    return int(count[0] + np.minimum(count[left[split]],
+                                     count[right[split]]).sum())
+
+
+def level_chunks(trees, slots):
+    """Chunks of ``slots`` nodes a level, the widest tree's at each level
+    (trees grown in lockstep share their chunks)."""
+    depths = [np.asarray(t.depth)[:t.n_nodes] for t in trees]
+    top = max(int(d.max()) for d in depths)
+    return sum(-(-max(int((d == lv).sum()) for d in depths) // slots)
+               for lv in range(1, top + 1))
+
+
+@pytest.mark.parametrize("builder", ["build_tree", "build_trees_batched",
+                                     "mesh"])
+def test_hist_rows_are_the_root_and_each_smaller_child(tmp_path, builder):
+    """The rows the level steps histogram, read in the reads the loop
+    already makes: the root's, then the smaller child of each sibling pair,
+    on levels split into 16-slot chunks, each chunk one read."""
+    table, y, x = small_table(m=2_000)
+    if builder == "build_trees_batched":
+        cfg = TreeConfig(max_depth=8, chunk_slots=16,
+                         task="regression_variance")
+        z = np.stack([x[:, 0], x[:, 1] * x[:, 2]]).astype(np.float32)
+        fit = lambda: build_trees_batched(table, z, cfg)[0]  # noqa: E731
+    elif builder == "mesh":
+        from repro.core.distributed import build_tree_distributed
+        mesh = jax.make_mesh((1, 1), ("data", "model"))
+        cfg = TreeConfig(max_depth=8, chunk_slots=16)
+        fit = lambda: [build_tree_distributed(  # noqa: E731
+            table, y, cfg, mesh=mesh, n_classes=3)]
+    else:
+        cfg = TreeConfig(max_depth=8, chunk_slots=16)
+        fit = lambda: [build_tree(table, y, cfg, n_classes=3)]  # noqa: E731
+    fit()
+    trees, delta, host = traced(fit, tmp_path)
+    spans = [a["rows"] for n, _, _, a in host if n == "udt.hist"]
+    assert sum(spans) == delta["hist_rows"] == sum(least_rows(t)
+                                                   for t in trees)
+    assert delta["hist_rows"] < len(y) * max(t.max_tree_depth for t in trees)
+    # one read per level chunk, the widest class's under the batched build
+    chunks = level_chunks(trees, 16)
+    assert len(spans) == delta["syncs"] == chunks
+    assert chunks > max(t.max_tree_depth for t in trees)

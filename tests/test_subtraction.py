@@ -8,8 +8,9 @@ values, so the subtraction is bit-identical to a recompute in float32 below
 2**24 examples; float moment channels (sum_y, sum_y2) agree to
 accumulation-order tolerance.
 """
-import numpy as np
+import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from repro.core import (TreeConfig, build_tree, class_stats, fit_bins,
@@ -316,3 +317,74 @@ def test_builder_resume_with_phist_cache():
               "parent"):
         np.testing.assert_array_equal(np.asarray(getattr(full, f)),
                                       np.asarray(getattr(resumed, f)))
+
+
+# the compacted ``segment`` scatter at tiles of TILE rows (K*C = 12 here),
+# so that the cases below take zero, one and several loop trips
+TILE = 16
+COMPACT_CASES = ["no_live", "under_tile", "one_tile", "tile_multiple",
+                 "all_live", "smaller_child", "weights", "vmap_lanes"]
+
+
+def _fresh(fn):
+    """A new jit of a histogram entry point, so that it is traced at the
+    patched tile size and not taken from the jit cache."""
+    return jax.jit(fn.__wrapped__,
+                   static_argnames=("num_slots", "n_bins", "backend"))
+
+
+def _slots_with_live(rng, m, s, n_live):
+    """[M] slots with exactly ``n_live`` rows live, spread over the rows."""
+    slot = np.full(m, -1, np.int32)
+    slot[rng.choice(m, size=n_live, replace=False)] = rng.integers(
+        0, s, size=n_live)
+    return jnp.asarray(slot)
+
+
+@pytest.mark.parametrize("case", COMPACT_CASES)
+def test_compacted_segment_histogram_equals_onehot(monkeypatch, case):
+    """Only live rows reach the ``segment`` scatter, compacted and taken a
+    tile at a time: integer-count channels equal the ``onehot`` backend's
+    bit for bit at every live count, a weights channel within
+    accumulation-order tolerance."""
+    from repro.core import histogram as hist_mod
+    m, s, k, b, c = 101, 8, 3, 7, 4
+    monkeypatch.setattr(hist_mod, "_TILE_UPDATES", TILE * k * c)
+    assert hist_mod._tile_rows(m, k, c) == TILE
+    rng = np.random.default_rng(COMPACT_CASES.index(case))
+    bins = jnp.asarray(rng.integers(0, b, size=(m, k)), jnp.int32)
+    stats = class_stats(jnp.asarray(rng.integers(0, c, size=m)), c)
+    full = _fresh(node_histogram)
+    kw = dict(num_slots=s, n_bins=b)
+    if case == "smaller_child":
+        slot = _slots_with_live(rng, m, s, 70)
+        compute = jnp.asarray(rng.uniform(size=s) < 0.5)
+        got = _fresh(node_histogram_smaller_child)(
+            bins, stats, slot, compute, backend="segment", **kw)
+        want = node_histogram_smaller_child(bins, stats, slot, compute,
+                                            backend="onehot", **kw)
+    elif case == "weights":
+        slot = _slots_with_live(rng, m, s, 3 * TILE + 5)
+        w = jnp.asarray(rng.uniform(0.25, 9.0, size=m).astype(np.float32))
+        got = full(bins, stats, slot, backend="segment", weights=w, **kw)
+        want = node_histogram(bins, stats, slot, backend="onehot",
+                              weights=w, **kw)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=1e-5, atol=1e-4)
+        return
+    elif case == "vmap_lanes":
+        # one lane per live count; the loop runs to the largest lane's
+        slot = jnp.stack([_slots_with_live(rng, m, s, n)
+                          for n in (0, 5, TILE, 2 * TILE + 1, m)])
+        got = jax.vmap(lambda sl: full(bins, stats, sl, backend="segment",
+                                       **kw))(slot)
+        want = jnp.stack([node_histogram(bins, stats, sl, backend="onehot",
+                                         **kw) for sl in slot])
+    else:
+        n_live = {"no_live": 0, "under_tile": TILE - 3, "one_tile": TILE,
+                  "tile_multiple": 3 * TILE, "all_live": m}[case]
+        slot = _slots_with_live(rng, m, s, n_live)
+        got = full(bins, stats, slot, backend="segment", **kw)
+        want = node_histogram(bins, stats, slot, backend="onehot", **kw)
+        assert float(got.sum()) == n_live * k
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
