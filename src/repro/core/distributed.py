@@ -70,8 +70,8 @@ from repro.core import obs
 from repro.core.binning import BinnedTable
 from repro.core.tree import (Tree, TreeConfig, _auto_chunk_slots, _chunk_step,
                              _chunk_step_classes, _grow, _grow_batched,
-                             _init_arrays, _node_predicate, _prepare,
-                             _route_step, _route_step_classes,
+                             _hist_channels, _init_arrays, _node_predicate,
+                             _prepare, _route_step, _route_step_classes,
                              _subtract_eligible)
 
 __all__ = ["DistConfig", "DistributedBuilder", "build_tree_distributed",
@@ -641,7 +641,8 @@ class DistributedBuilder:
         # conservatively uses the feature-shard row bytes.  Weighted builds
         # (GOSS / Newton hessians) keep eligibility only under the
         # float-tolerance contract — same gate as the local builder.
-        subtract = (((self.k_pad // self.f_shards) * self.b * c * 4,
+        subtract = (((self.k_pad // self.f_shards) * self.b
+                     * _hist_channels(c, config.task, weighted) * 4,
                      config.sub_cache_bytes)
                     if _subtract_eligible(config, self.m, weighted)
                     else None)
@@ -708,7 +709,8 @@ class DistributedBuilder:
                   hist_backend=config.hist_backend,
                   select_backend=config.select_backend, n_label_bins=1,
                   min_child_weight=config.min_child_weight)
-        subtract = (((self.k_pad // self.f_shards) * self.b * 3 * 4,
+        subtract = (((self.k_pad // self.f_shards) * self.b
+                     * _hist_channels(3, config.task, weighted) * 4,
                      config.sub_cache_bytes)
                     if _subtract_eligible(config, self.m, weighted)
                     else None)

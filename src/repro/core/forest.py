@@ -67,6 +67,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core import obs
 from repro.core.binning import BinnedTable
 from repro.core.losses import get_loss
 from repro.core.predict import (WALK_FIELDS, _walk, predict_bins,
@@ -76,6 +77,12 @@ from repro.core.tree import (Tree, TreeConfig, build_tree,
 
 __all__ = ["RandomForest", "GradientBoostedTrees", "GossConfig",
            "goss_sample_sharded_ref"]
+
+
+def _round(r: int, rows: int):
+    """The span of boosting round ``r`` over ``rows`` training rows, which
+    raises the ``rounds`` counter as it opens."""
+    return obs.counted("boost.round", "rounds", round=r, rows=rows)
 
 
 def _validate_fit_inputs(table: BinnedTable, y, sample_weight=None) -> None:
@@ -447,6 +454,17 @@ def goss_sample_sharded_ref(rank, key, *, d_shards, m_valid, q_top, q_oth):
     return w.reshape(m_pad)
 
 
+@functools.partial(jax.jit, static_argnums=0, static_argnames=("num_steps",))
+def _raw_update(walk, raw, lr, tree, bins, n_num, *, num_steps):
+    """Boosting's raw-score update ``raw + lr * walk(tree)``, one program
+    whose ops carry the ``boost.walk`` scope in their ``tf_op`` path (a
+    scope around an eager call reaches no compiled op).  ``walk`` is
+    ``predict_bins``, taken as a static argument so that the function the
+    module holds at call time is the one traced."""
+    with jax.named_scope("boost.walk"):
+        return raw + lr * walk(tree, bins, n_num, num_steps=num_steps)
+
+
 @functools.partial(jax.jit, static_argnames=("num_steps",))
 def _ensemble_predict(stacked, bins, n_num, lr, base, *, num_steps):
     """Batched Algorithm-7 walk over every tree of the ensemble: one vmap
@@ -596,36 +614,37 @@ class GradientBoostedTrees:
         num_steps = max(1, self.config.max_depth)
         start, raw, key = self._apply_resume(resume_from, digest, raw, key)
         for r in range(start, self.n_trees):
-            g, h = lo.grad_hess(y, raw)
-            # a row weight scales g and h alike, so the Newton target is
-            # weight-invariant; the weight enters through the h channel
-            # (and the leverage ranking) only.
-            z = lo.newton_target(g, h)
-            if sw is not None:
-                g, h = g * sw, h * sw
-            use_w = sw is not None or not lo.constant_hessian
-            if self.goss is None:
-                tree = build_tree(
-                    dev_table, z, self.config,
-                    sample_weight=h if use_w else None,
-                    level_callback=level_callback)
-            else:
-                key, sub = jax.random.split(key)
-                rank = g * jnp.sqrt(h) if use_w else g
-                idx, w = _goss_sample(rank, sub, top_n=top_n,
-                                      other_n=other_n, amp=amp)
-                if use_w:
-                    w = w * jnp.take(h, idx)    # GOSS amp x hessian weight
-                sub_table = dataclasses.replace(
-                    table, bins=jnp.take(bins, idx, axis=0))
-                tree = build_tree(sub_table, jnp.take(z, idx),
-                                  self.config, sample_weight=w,
-                                  level_callback=level_callback)
-            self.trees.append(tree)
-            # full-data raw scores update on device; num_steps is the
-            # static depth bound so no per-tree host sync happens here
-            raw = raw + self.learning_rate * predict_bins(
-                tree, bins, n_num_d, num_steps=num_steps)
+            with _round(r, m):
+                g, h = lo.grad_hess(y, raw)
+                # a row weight scales g and h alike, so the Newton target
+                # is weight-invariant; the weight enters through the h
+                # channel (and the leverage ranking) only.
+                z = lo.newton_target(g, h)
+                if sw is not None:
+                    g, h = g * sw, h * sw
+                use_w = sw is not None or not lo.constant_hessian
+                if self.goss is None:
+                    tree = build_tree(
+                        dev_table, z, self.config,
+                        sample_weight=h if use_w else None,
+                        level_callback=level_callback)
+                else:
+                    key, sub = jax.random.split(key)
+                    rank = g * jnp.sqrt(h) if use_w else g
+                    idx, w = _goss_sample(rank, sub, top_n=top_n,
+                                          other_n=other_n, amp=amp)
+                    if use_w:
+                        w = w * jnp.take(h, idx)  # GOSS amp x hessian weight
+                    sub_table = dataclasses.replace(
+                        table, bins=jnp.take(bins, idx, axis=0))
+                    tree = build_tree(sub_table, jnp.take(z, idx),
+                                      self.config, sample_weight=w,
+                                      level_callback=level_callback)
+                self.trees.append(tree)
+                # full-data raw scores update on device; num_steps is the
+                # static depth bound so no per-tree host sync happens here
+                raw = _raw_update(predict_bins, raw, self.learning_rate,
+                                  tree, bins, n_num_d, num_steps=num_steps)
             if round_callback is not None:
                 round_callback(self._round_state(r + 1, raw, key, digest))
         self.base = float(base)                 # one scalar sync at the end
@@ -680,35 +699,37 @@ class GradientBoostedTrees:
         lr = jnp.float32(self.learning_rate)
         start, raw, key = self._apply_resume(resume_from, digest, raw, key)
         for r in range(start, self.n_trees):
-            g, h = lo.grad_hess(y_i, raw)       # [C, M] each
-            z = lo.newton_target(g, h)
-            if sw is not None:
-                g, h = g * sw[None], h * sw[None]
-            if self.goss is None:
-                round_trees, arrays = build_trees_batched(
-                    dev_table, z, self.config, sample_weight=h,
-                    level_callback=level_callback)
-            else:
-                # ONE shared row draw per round (all class-trees see the
-                # same sampled rows — one subset gather, one build shape),
-                # ranked by the cross-class Newton leverage norm
-                # sqrt(sum_c g_c^2 h_c) = the L2 norm of the per-class
-                # |g_c| sqrt(h_c) leverages; each class then multiplies
-                # its own hessians onto the shared amplification weights.
-                key, sub = jax.random.split(key)
-                rank = jnp.sqrt(jnp.sum(g * g * h, axis=0))
-                idx, w = _goss_sample(rank, sub, top_n=top_n,
-                                      other_n=other_n, amp=amp)
-                sub_table = dataclasses.replace(
-                    table, bins=jnp.take(bins, idx, axis=0))
-                round_trees, arrays = build_trees_batched(
-                    sub_table, jnp.take(z, idx, axis=1), self.config,
-                    sample_weight=w[None] * jnp.take(h, idx, axis=1),
-                    level_callback=level_callback)
-            self.trees.extend(round_trees)
-            raw = raw + lr * walk_class_trees(
-                {f: arrays[f] for f in WALK_FIELDS}, bins, n_num_d,
-                num_steps=num_steps)
+            with _round(r, m):
+                g, h = lo.grad_hess(y_i, raw)   # [C, M] each
+                z = lo.newton_target(g, h)
+                if sw is not None:
+                    g, h = g * sw[None], h * sw[None]
+                if self.goss is None:
+                    round_trees, arrays = build_trees_batched(
+                        dev_table, z, self.config, sample_weight=h,
+                        level_callback=level_callback)
+                else:
+                    # ONE shared row draw per round (all class-trees see
+                    # the same sampled rows — one subset gather, one build
+                    # shape), ranked by the cross-class Newton leverage
+                    # norm sqrt(sum_c g_c^2 h_c) = the L2 norm of the
+                    # per-class |g_c| sqrt(h_c) leverages; each class then
+                    # multiplies its own hessians onto the shared
+                    # amplification weights.
+                    key, sub = jax.random.split(key)
+                    rank = jnp.sqrt(jnp.sum(g * g * h, axis=0))
+                    idx, w = _goss_sample(rank, sub, top_n=top_n,
+                                          other_n=other_n, amp=amp)
+                    sub_table = dataclasses.replace(
+                        table, bins=jnp.take(bins, idx, axis=0))
+                    round_trees, arrays = build_trees_batched(
+                        sub_table, jnp.take(z, idx, axis=1), self.config,
+                        sample_weight=w[None] * jnp.take(h, idx, axis=1),
+                        level_callback=level_callback)
+                self.trees.extend(round_trees)
+                raw = raw + lr * walk_class_trees(
+                    {f: arrays[f] for f in WALK_FIELDS}, bins, n_num_d,
+                    num_steps=num_steps)
             if round_callback is not None:
                 round_callback(self._round_state(r + 1, raw, key, digest))
         self.base = np.asarray(base, dtype=np.float32)   # [C], one sync
@@ -796,23 +817,25 @@ class GradientBoostedTrees:
             raw = stage(np.asarray(ck.raw, dtype=np.float32), 0.0,
                         np.float32)
         for r in range(start, self.n_trees):
-            key, sub = jax.random.split(key)
-            args = (y_d, raw, sub) + ((sw_d,) if sw_d is not None else ())
-            z, w, assign0 = sampler(*args)
-            if multiclass:
-                round_trees, arrays = builder.build_batched(
-                    z, sample_weight=w if use_w else None, assign=assign0,
-                    level_callback=level_callback)
-                self.trees.extend(round_trees)
-                raw = walk(raw, {f: arrays[f] for f in WALK_FIELDS},
-                           builder.bins_d, builder.n_num_d, lr)
-            else:
-                tree = builder.build(z, sample_weight=w if use_w else None,
-                                     assign=assign0,
-                                     level_callback=level_callback)
-                self.trees.append(tree)
-                raw = walk(raw, {f: getattr(tree, f) for f in WALK_FIELDS},
-                           builder.bins_d, builder.n_num_d, lr)
+            with _round(r, m):
+                key, sub = jax.random.split(key)
+                args = (y_d, raw, sub) + ((sw_d,) if sw_d is not None else ())
+                z, w, assign0 = sampler(*args)
+                if multiclass:
+                    round_trees, arrays = builder.build_batched(
+                        z, sample_weight=w if use_w else None,
+                        assign=assign0, level_callback=level_callback)
+                    self.trees.extend(round_trees)
+                    raw = walk(raw, {f: arrays[f] for f in WALK_FIELDS},
+                               builder.bins_d, builder.n_num_d, lr)
+                else:
+                    tree = builder.build(
+                        z, sample_weight=w if use_w else None,
+                        assign=assign0, level_callback=level_callback)
+                    self.trees.append(tree)
+                    raw = walk(raw,
+                               {f: getattr(tree, f) for f in WALK_FIELDS},
+                               builder.bins_d, builder.n_num_d, lr)
             if round_callback is not None:
                 round_callback(self._round_state(r + 1, raw, key, digest))
         self.base = base

@@ -4,7 +4,7 @@
 written into the profiler's own trace, beside the device's operations,
 with ``args`` as its stats.  With no trace running it costs one check.
 
-Four process counters, read with ``counters()``, each raised at the
+Five process counters, read with ``counters()``, each raised at the
 same boundary as a span so that a trace and the counters agree:
 
 * ``h2d_bytes``: bytes ``build_tree`` sent from host arrays to the device
@@ -13,7 +13,9 @@ same boundary as a span so that a trace and the counters agree:
 * ``hist_rows``: rows the level steps' feature histograms took in, read
   in those same reads (zero-length ``udt.hist`` spans, their ``rows`` arg);
 * ``compiles``: executables compiled or loaded from the persistent cache
-  (zero-length ``repro.compile`` spans, their ``fun`` arg the program).
+  (zero-length ``repro.compile`` spans, their ``fun`` arg the program);
+* ``rounds``: boosting rounds begun (``boost.round`` spans, their
+  ``round`` and ``rows`` args).
 """
 from __future__ import annotations
 
@@ -30,7 +32,8 @@ __all__ = ["span", "spanned", "counted", "host_bytes", "counters"]
 # persistent-cache load, with the compile's duration
 COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 
-_COUNTS = {"h2d_bytes": 0, "syncs": 0, "hist_rows": 0, "compiles": 0}
+_COUNTS = {"h2d_bytes": 0, "syncs": 0, "hist_rows": 0, "compiles": 0,
+           "rounds": 0}
 _LOCK = threading.Lock()     # fits and compiles may run on several threads
 
 span = jax.profiler.TraceAnnotation

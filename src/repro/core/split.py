@@ -1,12 +1,20 @@
 """Superfast Selection (paper Algorithms 2 & 4), fully vectorised.
 
 Given the per-node histograms ``H[S, K, B, C]`` (one O(M) pass, see
-``histogram.py``), a prefix sum along the bin axis makes EVERY candidate
-split an O(C) evaluation:
+``histogram.py``), prefix and suffix sums along the bin axis make EVERY
+candidate split an O(C) evaluation.  With ``prefix[b]`` the numeric bins
+up to b, ``suffix[b]`` the numeric bins above b and ``other`` the
+categorical and missing bins:
 
-  * numeric  "<= v" : pos = prefix[b],           neg = tot - pos
-  * numeric  ">  v" : pos = tot_num - prefix[b], neg = tot - pos
-  * categorical "=" : pos = H[b],                neg = tot - pos
+  * numeric  "<= v" : pos = prefix[b], neg = suffix[b] + other
+  * numeric  ">  v" : pos = suffix[b], neg = prefix[b] + other
+  * categorical "=" : pos = H[b],      neg = the bins before b + those after
+
+Each side is a sum over the bins that go to it, never a total minus the
+other side: a side that holds no row reads exactly 0 weight and 0
+gradient, so ``min_leaf`` masks it.  (In float32 a weighted total minus
+the other side leaves a rounding residue on the empty side, which then
+passes ``min_leaf`` and, in boosting, outscores every real split.)
 
 Note "<=" and ">" are NOT complements when categorical / missing values are
 present (both comparisons evaluate False on them, paper Table 3), which is
@@ -42,29 +50,36 @@ class SplitDecision(NamedTuple):
 
 
 def _candidate_stats(hist, n_num, n_cat):
-    """Build pos/neg stat tensors for all three candidate families.
+    """Build pos/neg stat tensors for all three candidate families, each
+    side summed over the bins that go to it.
 
     hist: [S, K, B, C];  n_num, n_cat: [K] ints.
     Returns pos, neg of shape [3, S, K, B, C] and validity mask [3, K, B].
     """
-    s, k, b, c = hist.shape
-    bin_ids = jnp.arange(b, dtype=jnp.int32)
+    c = hist.shape[3]
+    bin_ids = jnp.arange(hist.shape[2], dtype=jnp.int32)
     is_num = bin_ids[None, :] < n_num[:, None]                      # [K,B]
     is_cat = (bin_ids[None, :] >= n_num[:, None]) & (
         bin_ids[None, :] < (n_num + n_cat)[:, None])                # [K,B]
 
-    tot = hist.sum(axis=2, keepdims=True)                           # [S,K,1,C]
-    num_hist = hist * is_num[None, :, :, None]
-    prefix = jnp.cumsum(num_hist, axis=2)                           # [S,K,B,C]
-    tot_num = prefix[:, :, -1:, :]                                  # [S,K,1,C]
+    num_hist = jnp.where(is_num[None, :, :, None], hist, 0.0)
+    other = jnp.where(is_num[None, :, :, None], 0.0, hist).sum(
+        axis=2, keepdims=True)                                      # [S,K,1,C]
+    flip = functools.partial(jnp.flip, axis=2)
+    # one inclusive scan along the bins of the numeric and of all bins,
+    # each also mirrored: sums up to b, and from b on
+    scans = jnp.cumsum(jnp.concatenate(
+        [num_hist, flip(num_hist), hist, flip(hist)], axis=3), axis=2)
+    zero = jnp.zeros_like(hist[:, :, :1])
+    after = lambda x: jnp.concatenate([x[:, :, 1:], zero], axis=2)   # x[b+1]
+    before = lambda x: jnp.concatenate([zero, x[:, :, :-1]], axis=2)  # x[b-1]
+    prefix = scans[..., :c]                                         # bins <= b
+    suffix = after(flip(scans[..., c:2 * c]))                       # bins > b
+    rest = (before(scans[..., 2 * c:3 * c])
+            + after(flip(scans[..., 3 * c:])))                      # bins != b
 
-    pos_le = prefix
-    pos_gt = tot_num - prefix
-    pos_eq = hist
-    pos = jnp.stack([pos_le, pos_gt, pos_eq])                       # [3,S,K,B,C]
-    neg = tot[None] - pos
-    # the last numeric candidate "<= max" is degenerate only if there are no
-    # categorical/missing counts; generic emptiness masking below handles it.
+    pos = jnp.stack([prefix, suffix, hist])                         # [3,S,K,B,C]
+    neg = jnp.stack([suffix + other, prefix + other, rest])
     valid = jnp.stack([is_num, is_num, is_cat])                     # [3,K,B]
     return pos, neg, valid
 

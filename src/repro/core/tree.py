@@ -123,6 +123,13 @@ class BuildState(NamedTuple):
     phist_base: int = -1
 
 
+def _hist_channels(c: int, task: str, weighted: bool) -> int:
+    """Channels of a level step's histograms: the task's ``c`` statistics,
+    and for a weighted ``regression_variance`` build one more, the row
+    count that tells an empty bin from a rounding residue."""
+    return c + int(weighted and task == "regression_variance")
+
+
 def _auto_chunk_slots(k: int, b: int, c: int, budget: int) -> int:
     s = max(1, budget // max(1, k * b * c * 4))
     return int(min(4096, s))
@@ -208,7 +215,9 @@ def _chunk_step_impl(bins, stats, lbins, y, assign, arrays, phist_pairs, n_num,
 
     ``weighted`` + ``weights`` ([M] f32) switch on the per-example weight
     channel: histograms accumulate ``w[i] * stats[i]`` (in-kernel on the
-    pallas backend), so every count / label / purity statistic below is the
+    pallas backend; a ``regression_variance`` step weights its moment rows
+    itself and adds each bin's row count, ``_hist_channels``), so every
+    count / label / purity statistic below is the
     GOSS-amplified unbiased estimate of its full-data value, and
     ``min_samples_split`` / ``min_samples_leaf`` bound the estimated
     full-data counts.  Under data parallelism the weights arrive sharded
@@ -325,7 +334,8 @@ def _chunk_step_impl(bins, stats, lbins, y, assign, arrays, phist_pairs, n_num,
     in_chunk = slot_ids < chunk_n
     node_ids = jnp.where(in_chunk, chunk_start + slot_ids, max_nodes)
 
-    w = weights if weighted else None
+    # a weighted regression_variance step weights its own rows (below)
+    w = weights if weighted and task != "regression_variance" else None
 
     def build_hist(stats_rows):
         """One level-chunk histogram: full scatter, or smaller-child scatter
@@ -409,7 +419,19 @@ def _chunk_step_impl(bins, stats, lbins, y, assign, arrays, phist_pairs, n_num,
             dec, mc = regather((dec, mc))
     elif task == "regression_variance":
         with histogram_scope:
-            hist, rows = build_hist(moment_stats(y))
+            if weighted:
+                # weighted moments (w, wy, wy^2) and the row count.  A bin
+                # of a derived sibling (parent - smaller child) that holds
+                # none of its rows keeps a float rounding residue in each
+                # weighted channel, but its count is exact (below 2**24
+                # rows, as subtraction requires) and reads 0, so the bin
+                # is cleared: an empty side then sums to exactly 0.
+                hist, rows = build_hist(jnp.concatenate(
+                    [moment_stats(y) * weights[:, None],
+                     jnp.ones_like(y)[:, None]], axis=1))
+                hist = jnp.where(hist[..., 3:] > 0, hist, 0.0)
+            else:
+                hist, rows = build_hist(moment_stats(y))
         with update_scope:
             tot = hist[:, 0].sum(axis=1)                            # [S,3]
             count_f = tot[:, 0]
@@ -900,17 +922,18 @@ def build_trees_batched(table: BinnedTable, z, config: TreeConfig,
     # no class axis); see _prepare.
     stats = jnp.zeros((m, 3), jnp.float32)
     lbins = jnp.zeros((m,), jnp.int32)
+    c = _hist_channels(3, config.task, weights is not None)
 
     max_nodes = config.max_nodes or min(2 * m + 1, 1 << 22)
     s_cap = config.chunk_slots or _auto_chunk_slots(
-        k, b, 3, config.hist_budget_bytes)
+        k, b, c, config.hist_budget_bytes)
     arrays = {k_: jnp.broadcast_to(v[None], (n_stack,) + v.shape)
               for k_, v in _init_arrays(max_nodes).items()}
     if assign0 is None:
         assign = jnp.zeros((n_stack, m), dtype=jnp.int32)
     else:
         assign = jnp.broadcast_to(assign0, (n_stack, m))
-    subtract = ((k * b * 3 * 4, config.sub_cache_bytes)
+    subtract = ((k * b * c * 4, config.sub_cache_bytes)
                 if _subtract_eligible(config, m, weights is not None)
                 else None)
 
@@ -997,6 +1020,7 @@ def build_tree(table: BinnedTable, y, config: TreeConfig = TreeConfig(),
         n_num = jnp.asarray(table.n_num)
         n_cat = jnp.asarray(table.n_cat)
 
+    c = _hist_channels(c, config.task, weights is not None)
     max_nodes = config.max_nodes or min(2 * m + 1, 1 << 22)
     s_cap = config.chunk_slots or _auto_chunk_slots(
         k, b, c, config.hist_budget_bytes)

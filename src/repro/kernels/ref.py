@@ -9,7 +9,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import heuristics as H
-from repro.core.split import NEG_INF
+from repro.core.split import NEG_INF, _candidate_stats
 
 __all__ = ["histogram_ref", "sibling_ref", "split_scan_ref"]
 
@@ -63,24 +63,13 @@ def split_scan_ref(hist, n_num, n_cat, *, heuristic="info_gain", min_leaf=1):
     """
     h_fn = H.get(heuristic)
     s, k, b, c = hist.shape
-    bin_ids = jnp.arange(b, dtype=jnp.int32)
-    is_num = bin_ids[None, :] < n_num[:, None]
-    is_cat = (bin_ids[None, :] >= n_num[:, None]) & (
-        bin_ids[None, :] < (n_num + n_cat)[:, None])
-
-    tot = hist.sum(axis=2, keepdims=True)
-    num_hist = hist * is_num[None, :, :, None]
-    prefix = jnp.cumsum(num_hist, axis=2)
-    tot_num = prefix[:, :, -1:, :]
-
-    pos = jnp.stack([prefix, tot_num - prefix, hist])       # [3,S,K,B,C]
-    neg = tot[None] - pos
+    # the unfused path's candidate sides, each summed over its own bins
+    pos, neg, valid = _candidate_stats(hist, n_num, n_cat)  # [3,S,K,B,C]
     moment = heuristic == "sse"
     cnt_p = pos[..., 0] if moment else pos.sum(-1)
     cnt_n = neg[..., 0] if moment else neg.sum(-1)
     score = h_fn(pos, neg)
-    valid = jnp.stack([is_num, is_num, is_cat])[:, None]    # [3,1,K,B]
-    ok = valid & (cnt_p >= min_leaf) & (cnt_n >= min_leaf)
+    ok = valid[:, None] & (cnt_p >= min_leaf) & (cnt_n >= min_leaf)
     score = jnp.where(ok, score, NEG_INF)                   # [3,S,K,B]
 
     flat = score.transpose(1, 2, 0, 3).reshape(s, k, 3 * b)

@@ -4,7 +4,7 @@ Superfast Selection's inner loop (paper Algorithm 4 lines 10-36).  The
 unfused jnp path materialises pos/neg tensors of shape [3, S, K, B, C] in
 HBM — 6x the histogram's own footprint — making selection memory-bound.
 This kernel keeps one node slot's [K, B, C] histogram block in VMEM, runs
-the bin-axis prefix sum, evaluates the heuristic for all 3 candidate
+the bin-axis sums, evaluates the heuristic for all 3 candidate
 families, and reduces to a single (score, bin, op) triple per (node-slot,
 feature).  HBM traffic drops from O(S*K*B*C * 7) to O(S*K*B*C + S*K) (read
 once, write 3 scalars).
@@ -12,10 +12,11 @@ once, write 3 scalars).
 Layout: hist arrives as [S, K, B, C] (bins on sublanes, classes on lanes,
 so the heuristics reduce over the lane axis exactly as the jnp path
 does), the grid is (S, K-blocks), and each program walks its features with
-a ``fori_loop``.  The bin-axis prefix sum is a matmul with a
-lower-triangular ones matrix (exact at HIGHEST precision for integer
-counts below 2**24).  Per-feature ``n_num`` / ``n_cat`` sit in SMEM; the
-per-feature winners are assembled into lane-dense [1, Kb] output rows.
+a ``fori_loop``.  Each side of a candidate is summed over the bins that
+go to it, as in ``core/split.py``: the bin-axis sums are matmuls with 0/1
+matrices (exact at HIGHEST precision for integer counts below 2**24).
+Per-feature ``n_num`` / ``n_cat`` sit in SMEM; the per-feature winners
+are assembled into lane-dense [1, Kb] output rows.
 """
 from __future__ import annotations
 
@@ -42,11 +43,20 @@ def _scan_kernel(nnum_ref, ncat_ref, hist_ref, score_ref, bin_ref, op_ref, *,
     moment = heuristic == "sse"
     kb = pl.program_id(1)
     bin_ids = jax.lax.broadcasted_iota(jnp.int32, (n_bins, 1), 0)
-    # tri[i, j] = j <= i, so tri @ x is the inclusive prefix sum over bins
-    tri = (jax.lax.broadcasted_iota(jnp.int32, (n_bins, n_bins), 1)
-           <= jax.lax.broadcasted_iota(jnp.int32, (n_bins, n_bins), 0)
-           ).astype(jnp.float32)
+    row = jax.lax.broadcasted_iota(jnp.int32, (n_bins, n_bins), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (n_bins, n_bins), 1)
+    # m @ x sums, for each bin i, the bins j that m[i, j] marks: up to i
+    # (the inclusive prefix), after i, or every bin but i
+    upto = (col <= row).astype(jnp.float32)
+    above = (col > row).astype(jnp.float32)
+    apart = (col != row).astype(jnp.float32)
     lanes = jax.lax.broadcasted_iota(jnp.int32, (1, n_feat), 1)
+
+    def scan(m, x):
+        return jax.lax.dot_general(
+            m, x, (((1,), (0,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,
+            preferred_element_type=jnp.float32)             # [B, C]
 
     def one_feature(f, carry):
         best_s, best_b, best_o = carry
@@ -56,24 +66,24 @@ def _scan_kernel(nnum_ref, ncat_ref, hist_ref, score_ref, bin_ref, op_ref, *,
         is_num = bin_ids < n_num                            # [B, 1]
         is_cat = (bin_ids >= n_num) & (bin_ids < n_num + n_cat)
 
-        tot = hist.sum(axis=0, keepdims=True)               # [1, C]
+        # each side is a sum over the bins that go to it (core/split.py),
+        # so a side that holds no row reads exactly 0
         num_hist = jnp.where(is_num, hist, 0.0)
-        prefix = jax.lax.dot_general(
-            tri, num_hist, (((1,), (0,)), ((), ())),
-            precision=jax.lax.Precision.HIGHEST,
-            preferred_element_type=jnp.float32)             # [B, C]
-        tot_num = prefix[n_bins - 1:, :]                    # [1, C]
+        other = jnp.where(is_num, 0.0, hist).sum(axis=0, keepdims=True)
+        prefix = scan(upto, num_hist)                       # numeric <= b
+        suffix = scan(above, num_hist)                      # numeric > b
+        rest = scan(apart, hist)                            # every bin but b
 
-        def family(pos, valid):
-            neg = tot - pos
+        def family(pos, neg, valid):
             cnt_p = pos[:, :1] if moment else pos.sum(-1, keepdims=True)
             cnt_n = neg[:, :1] if moment else neg.sum(-1, keepdims=True)
             s = h_fn(pos, neg)[:, None]                     # [B, 1]
             ok = valid & (cnt_p >= min_leaf) & (cnt_n >= min_leaf)
             return jnp.where(ok, s, NEG_INF)
 
-        fams = (family(prefix, is_num), family(tot_num - prefix, is_num),
-                family(hist, is_cat))
+        fams = (family(prefix, suffix + other, is_num),
+                family(suffix, prefix + other, is_num),
+                family(hist, rest, is_cat))
         # first maximum in op-major (op, bin) order == argmax over the
         # flattened [3, B] scores, as ref.split_scan_ref takes it
         top = functools.reduce(

@@ -282,3 +282,58 @@ def test_hist_rows_are_the_root_and_each_smaller_child(tmp_path, builder):
     chunks = level_chunks(trees, 16)
     assert len(spans) == delta["syncs"] == chunks
     assert chunks > max(t.max_tree_depth for t in trees)
+
+
+@pytest.mark.parametrize("path", ["single", "multiclass", "mesh"])
+def test_each_boosting_round_is_a_span_that_raises_rounds(tmp_path, path):
+    """Three rounds of ``GradientBoostedTrees.fit`` open three
+    ``boost.round`` spans, rounds 0, 1, 2 over every row, and raise the
+    ``rounds`` counter by 3; each round's trees are grown inside it."""
+    from repro.core import GradientBoostedTrees
+    table, y, _ = small_table()
+    loss, kw = "logistic", {}
+    if path == "multiclass":
+        loss = "softmax"
+    else:
+        y = (y > 0).astype(np.float32)
+    if path == "mesh":
+        kw = dict(mesh=jax.make_mesh((1, 1), ("data", "model")))
+    cfg = TreeConfig(max_depth=3, task="regression_variance")
+    fit = lambda: GradientBoostedTrees(  # noqa: E731
+        n_trees=3, loss=loss, learning_rate=0.3, config=cfg).fit(
+            table, y, **kw).trees[-1].label
+    fit()
+    _, delta, host = traced(fit, tmp_path)
+    rounds = [ev for ev in host if ev[0] == "boost.round"]
+    assert [a["round"] for *_, a in rounds] == [0, 1, 2]
+    assert all(a["rows"] == len(y) for *_, a in rounds)
+    assert delta["rounds"] == 3
+    trees = [ev for ev in host if ev[0] == "udt.tree"]
+    assert trees and all(any(inside(t, r) for r in rounds) for t in trees)
+    assert delta["compiles"] == 0
+
+
+def test_the_raw_score_update_names_its_scope():
+    """Every instruction of the compiled raw-score update carries the
+    ``boost.walk`` scope in its op metadata (parameters aside), and the
+    update adds the walk to the scores as the fit's own eager sum does."""
+    from repro.core import GradientBoostedTrees, forest
+    from repro.core.predict import predict_bins
+    table, y, _ = small_table(m=256)
+    y = (y > 0).astype(np.float32)
+    gbt = GradientBoostedTrees(n_trees=1, loss="logistic", config=TreeConfig(
+        max_depth=3, task="regression_variance")).fit(table, y)
+    tree, bins = gbt.trees[0], jnp.asarray(table.bins)
+    n_num, raw = jnp.asarray(table.n_num), jnp.linspace(-1.0, 1.0, 256)
+    args = (predict_bins, raw, 0.3, tree, bins, n_num)
+    lowered = forest._raw_update.lower(*args, num_steps=3)
+    assert "boost.walk" in lowered.as_text(debug_info=True)
+    text = lowered.compile().as_text()
+    names = [m.group(1) for line in text.splitlines()
+             if " = " in line and "parameter(" not in line
+             for m in [re.search(r'op_name="([^"]+)"', line)] if m]
+    assert len(names) > 10
+    assert all("boost.walk" in n.split("/") for n in names)
+    want = raw + 0.3 * predict_bins(tree, bins, n_num, num_steps=3)
+    np.testing.assert_allclose(forest._raw_update(*args, num_steps=3), want,
+                               rtol=1e-6, atol=1e-7)

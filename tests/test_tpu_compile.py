@@ -115,17 +115,21 @@ def test_segment_level_step_fits_v5e_hbm(one_chip, step):
     moments, one vmapped lane per class)."""
     import jax
     import jax.numpy as jnp
-    from repro.core.tree import _chunk_step, _chunk_step_classes, _init_arrays
+    from repro.core.tree import (_chunk_step, _chunk_step_classes,
+                                 _hist_channels, _init_arrays)
     sd = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
     s, n_nodes = 32, 2 * M + 1
     lanes = () if step == "tree" else (C,)
     c = C if step == "tree" else 3
+    # the cached parent histograms carry a weighted step's row count too
+    hc = c if step == "tree" else _hist_channels(c, "regression_variance",
+                                                 True)
     arrays = {f: sd(lanes + a.shape, a.dtype)
               for f, a in _init_arrays(n_nodes).items()}
     args = [sd((M, K), jnp.int32), sd((M, c), jnp.float32),
             sd((M,), jnp.int32), sd(lanes + (M,), jnp.float32),
             sd(lanes + (M,), jnp.int32), arrays,
-            sd(lanes + (s // 2, K, B, c), jnp.float32),
+            sd(lanes + (s // 2, K, B, hc), jnp.float32),
             sd((K,), jnp.int32), sd((K,), jnp.int32)]
     args += [sd(lanes, jnp.int32)] * 3 + [sd((), jnp.int32)]
     kw = dict(num_slots=s, n_bins=B, min_samples_split=2,
