@@ -13,9 +13,12 @@ validation example's root->leaf path once.  Along a path
     post-selection stopping rule, never a candidate mask (TreeConfig);
   * ``max_depth`` is a clamp.
 
-Every grid cell then costs O(1) per example; ``sweep`` vmaps the whole
-``(max_depth x min_samples_split x min_child_weight)`` grid on device and
-— for ``GradientBoostedTrees`` — adds ``n_rounds`` as a prefix sum over
+So a walk leaves path position t exactly when both gates hold at t, and
+a single tree's metric at every depth is a prefix sum over t of masked
+row sums: ``sweep`` prices the whole ``(max_depth x min_samples_split x
+min_child_weight)`` grid on device in O(M T) elementwise work per
+(smin, mcw) cell, with no gather over examples.  For
+``GradientBoostedTrees`` it adds ``n_rounds`` as a prefix sum over
 per-round path tables (round r's trees never depend on predict-time
 pruning, and the fit's PRNG key splits sequentially per round, so the
 first r trees of one fit ARE the retrained r-round ensemble).
@@ -173,26 +176,38 @@ def _grid_counts_body(lab, cnt, cmc, y, valid, smin, mcw, dmax, *,
     (classification — summation-order independent, so the sharded psum is
     bit-exact) or f32 SSE sums (regression).
 
-    ``jax.lax.map`` (not vmap) over the dmax axis keeps the peak
-    intermediate at [M, Ns, Nw] — vmapping would materialise the full
-    [Nd, M, Ns, Nw] index tensor."""
+    A row's walk under cell (s, w) stops at path index ``stop`` and under
+    ``dmax`` d answers at ``k = clip(min(stop, d - 1), 0, T - 1)``.  Both
+    gates fail monotonically along a path, so the walk leaves position t
+    exactly when the gates hold at t, and the total at every k is one
+    prefix sum over t of two masked row sums:
+
+        E[t] = sum_m [stop == t] v[m, t]    rows that answer at t for k > t
+        F[t] = sum_m [stop >= t] v[m, t]    rows clamped at t when k == t
+        total[k] = E[0] + ... + E[k-1] + F[k]
+
+    with ``v`` the row's correct-prediction indicator or squared error —
+    non-negative terms, so the f32 sums do not cancel.  No gather over
+    rows; the peak intermediate is the [M, T, Ns, Nw] gate mask, and the
+    depth axis reads the [T, Ns, Nw] totals."""
     m, t_len = lab.shape
     ns, nw = smin.shape[0], mcw.shape[0]
-    idx_s, idx_w = _stop_indices(cnt, cmc, smin, mcw)
-    stop = jnp.minimum(idx_s[:, :, None], idx_w[:, None, :])    # [M,Ns,Nw]
-
-    def per_dmax(d):
-        idx = jnp.clip(jnp.minimum(stop, d - 1), 0, t_len - 1)
-        pred = jnp.take_along_axis(lab, idx.reshape(m, ns * nw),
-                                   axis=1).reshape(m, ns, nw)
-        if classification:
-            ok = (pred == y[:, None, None]) & valid[:, None, None]
-            return ok.sum(axis=0).astype(jnp.int32)             # [Ns,Nw]
-        err = jnp.where(valid[:, None, None],
-                        (pred - y[:, None, None]) ** 2, 0.0)
-        return err.sum(axis=0)                                  # [Ns,Nw] f32
-
-    return jax.lax.map(per_dmax, dmax)                          # [Nd,Ns,Nw]
+    # descends[m, t, s, w]: both gates hold at position t, stop > t (mcw
+    # <= 0 disables its gate entirely — same rule as the predict walk)
+    descends = ((cnt[:, :-1, None, None] >= smin[None, None, :, None])
+                & ((mcw <= 0) | (cmc[:, :-1, None, None] > mcw)))
+    reach = jnp.concatenate([jnp.ones((m, 1, ns, nw), bool), descends],
+                            axis=1)                     # [M,T,Ns,Nw] stop>=t
+    if classification:
+        v = ((lab == y[:, None]) & valid[:, None]).astype(jnp.int32)
+    else:
+        v = jnp.where(valid[:, None], (lab - y[:, None]) ** 2, 0.0)
+    v = v[:, :, None, None]
+    clamped = jnp.where(reach, v, 0).sum(axis=0)                # F [T,Ns,Nw]
+    stopped = jnp.where(reach[:, :-1] & ~descends, v[:, :-1], 0).sum(axis=0)
+    before = jnp.cumsum(stopped, axis=0)                        # [T-1,Ns,Nw]
+    totals = clamped + jnp.concatenate([jnp.zeros_like(clamped[:1]), before])
+    return totals[jnp.clip(dmax - 1, 0, t_len - 1)]             # [Nd,Ns,Nw]
 
 
 _grid_counts = functools.partial(

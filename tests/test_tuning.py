@@ -237,6 +237,71 @@ def test_sweep_front_prices_cost_quality(setup):
     assert res.best.metric == res.metric.max()
 
 
+def _gather_grid_oracle(lab, cnt, cmc, y, valid, smin, mcw, dmax,
+                        classification):
+    """numpy copy of the grid's gather formulation: the stop index per
+    (row, smin, mcw) from two prefix counts, then one take along the path
+    per dmax (regression in float64)."""
+    m, t_len = lab.shape
+    idx_s = (cnt[:, :, None] >= smin[None, None, :]).sum(1)
+    pass_w = (mcw[None, None, :] <= 0) | (cmc[:, :, None] > mcw[None, None, :])
+    stop = np.minimum(idx_s[:, :, None], pass_w.sum(1)[:, None, :])
+    out = []
+    for d in dmax:
+        idx = np.clip(np.minimum(stop, d - 1), 0, t_len - 1)
+        pred = np.take_along_axis(lab, idx.reshape(m, -1),
+                                  axis=1).reshape(stop.shape)
+        yy, vv = y[:, None, None], valid[:, None, None]
+        if classification:
+            out.append(((pred == yy) & vv).sum(0))
+        else:
+            out.append(np.where(vv, (pred.astype(np.float64) - yy) ** 2,
+                                0.0).sum(0))
+    return np.stack(out)
+
+
+@pytest.mark.parametrize("classification", [True, False],
+                         ids=["cls", "reg"])
+@pytest.mark.parametrize("t_len", [1, 2, 7])
+def test_grid_counts_match_gather_oracle(t_len, classification):
+    """The prefix-sum grid equals the gather formulation on random
+    monotone path tables: rows switched off, dmax with 0, values past T,
+    duplicates and out of order, smin out of order with duplicates and the
+    sharded pad's Int32.max sentinel, mcw with 0, a negative value and
+    several values.  Classification counts are exact; regression sums
+    hold to 1e-5 of a float64 oracle."""
+    import jax.numpy as jnp
+    from repro.core.tuning import _grid_counts
+    rng = np.random.default_rng(17 + t_len)
+    m = 500
+    cnt = -np.sort(-rng.integers(1, 120, size=(m, t_len)), axis=1)
+    cmc = rng.integers(0, 30, size=(m, t_len)).astype(np.float32)
+    cmc[rng.random((m, t_len)) < 0.2] = np.inf
+    cmc = np.minimum.accumulate(cmc, axis=1)
+    if classification:
+        lab = rng.integers(0, 3, size=(m, t_len)).astype(np.float32)
+        y = rng.integers(0, 3, size=m).astype(np.float32)
+    else:
+        lab = rng.normal(0, 10, size=(m, t_len)).astype(np.float32)
+        y = rng.normal(0, 10, size=m).astype(np.float32)
+    valid = rng.random(m) > 0.15
+    smin = np.array([40, 0, 7, 40, 119, 2, np.iinfo(np.int32).max, 65],
+                    dtype=np.int32)
+    mcw = np.array([0.0, 5.0, -1.0, 20.0, 5.0], dtype=np.float32)
+    dmax = np.array([3, 0, 9, 1, 3, t_len + 4, 2, t_len], dtype=np.int32)
+    got = np.asarray(_grid_counts(
+        *map(jnp.asarray, (lab, cnt.astype(np.int32), cmc, y, valid, smin,
+                           mcw, dmax)), classification=classification))
+    want = _gather_grid_oracle(lab, cnt, cmc, y, valid, smin, mcw, dmax,
+                               classification)
+    assert got.shape == (len(dmax), len(smin), len(mcw))
+    if classification:
+        assert got.dtype == np.int32
+        np.testing.assert_array_equal(got, want)
+    else:
+        np.testing.assert_allclose(got, want, rtol=1e-5)
+
+
 def test_pareto_front_property_non_dominated():
     """hypothesis: for arbitrary (metric, nodes, bytes) grids the front is
     mutually non-dominated AND every input point is weakly dominated by a
